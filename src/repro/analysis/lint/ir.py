@@ -7,9 +7,13 @@ proper IR:
 
   * :class:`Instruction` — name, opcode, result shape(s), operand names,
     called computations, and the ``metadata={...}`` attributes
-    (``op_name`` / ``source_file`` — the latter is how interpret-mode
-    Pallas kernel bodies, which leak into CPU HLO as plain ops, are
-    recognized and exempted from materialization rules).
+    (``op_name`` and the op's source file — the latter is how
+    interpret-mode Pallas kernel bodies, which leak into CPU HLO as
+    plain ops, are recognized and exempted from materialization rules).
+    The source file comes from an inline ``source_file="..."`` or, as
+    current XLA prints it, from ``stack_frame_id=N`` resolved through
+    the module's ``FileNames`` / ``FileLocations`` / ``StackFrames``
+    tables.
   * :class:`HloComputation` — ordered instructions + ROOT.
   * :class:`HloGraph` — all computations, global def-use edges
     (instruction names are module-unique), caller links, and the
@@ -57,6 +61,12 @@ _CALLED_RE = re.compile(
     r"(\{[^}]*\}|%[\w.\-]+)")
 _NAME_RE = re.compile(r"%([\w.\-]+)")
 _META_FILE_RE = re.compile(r'source_file="([^"]*)"')
+_META_FRAME_RE = re.compile(r"stack_frame_id=(\d+)")
+_TABLE_ROW_RE = re.compile(r"^(\d+)\s+(.*)$")
+_FILE_ID_RE = re.compile(r"file_name_id=(\d+)")
+_LOCATION_ID_RE = re.compile(r"file_location_id=(\d+)")
+_STACK_TABLES = ("FileNames", "FunctionNames", "FileLocations",
+                 "StackFrames")
 _META_OP_RE = re.compile(r'op_name="([^"]*)"')
 _KERNEL_PATH_RE = re.compile(r"kernels")
 _ALIAS_PAIR_RE = re.compile(r"\(\s*(\d+)\s*,")
@@ -276,21 +286,48 @@ def _parse_alias_pairs(header: str) -> int:
     return 0
 
 
+def _frame_files(tables: Dict[str, Dict[int, str]]) -> Dict[int, str]:
+    """stack frame id -> source file of the frame's own location."""
+    out = {}
+    for fid, frame in tables["StackFrames"].items():
+        loc = _LOCATION_ID_RE.search(frame)
+        loc_row = tables["FileLocations"].get(int(loc.group(1))) if loc \
+            else None
+        fname = _FILE_ID_RE.search(loc_row) if loc_row else None
+        if fname:
+            out[fid] = tables["FileNames"].get(int(fname.group(1)),
+                                               "").strip('"')
+    return out
+
+
 def parse_hlo(hlo_text: str) -> HloGraph:
     """Parse post-optimization HLO text into an :class:`HloGraph`."""
     g = HloGraph()
     current: Optional[HloComputation] = None
     implicit: Optional[HloComputation] = None
+    tables: Dict[str, Dict[int, str]] = {t: {} for t in _STACK_TABLES}
+    table: Optional[str] = None
+    frames: Dict[str, int] = {}         # instruction -> stack frame id
 
     for lineno, raw in enumerate(hlo_text.splitlines(), start=1):
         line = raw.rstrip()
         stripped = line.strip()
         if not stripped:
+            table = None
             continue
         if stripped.startswith("HloModule"):
             g.module_name = stripped.split(",", 1)[0].split()[-1]
             g.alias_pairs = max(g.alias_pairs, _parse_alias_pairs(stripped))
             continue
+        if stripped in _STACK_TABLES:
+            table = stripped
+            continue
+        if table is not None:
+            row = _TABLE_ROW_RE.match(stripped)
+            if row is not None:
+                tables[table][int(row.group(1))] = row.group(2)
+                continue
+            table = None
         m = _INSTR_RE.match(line)
         if m is None:
             cm = _COMP_RE.match(line)
@@ -328,6 +365,9 @@ def parse_hlo(hlo_text: str) -> HloGraph:
             called.extend(_NAME_RE.findall(cm2.group(1)))
         fm = _META_FILE_RE.search(attrs)
         om = _META_OP_RE.search(attrs)
+        sm = _META_FRAME_RE.search(attrs)
+        if fm is None and sm is not None:
+            frames[name] = int(sm.group(1))
 
         if current is None:
             if implicit is None:
@@ -345,4 +385,8 @@ def parse_hlo(hlo_text: str) -> HloGraph:
             op_name=om.group(1) if om else "",
             source_file=fm.group(1) if fm else "",
             param_index=param_index))
+    if frames:
+        files = _frame_files(tables)
+        for name, fid in frames.items():
+            g.instructions[name].source_file = files.get(fid, "")
     return g

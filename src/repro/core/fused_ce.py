@@ -41,7 +41,9 @@ def _flatten(h: jax.Array, y: jax.Array):
     raise ValueError(f"hidden states must be rank 2 or 3, got {h.shape}")
 
 
-def _default_impl() -> str:
+def default_impl() -> str:
+    """The local loss 'auto' resolves to: the Pallas kernels on a TPU,
+    the `lax.scan` streaming loss elsewhere."""
     return "pallas" if jax.default_backend() == "tpu" else "streaming"
 
 
@@ -76,7 +78,7 @@ def fused_cross_entropy(
     cfg = cfg or LossConfig()
     hf, yf = _flatten(h, targets)
     if impl == "auto":
-        impl = _default_impl()
+        impl = default_impl()
     if impl == "canonical":
         out = canonical_loss(hf, w, yf, cfg)
     elif impl == "streaming":

@@ -39,7 +39,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.types import LossConfig
 from repro.core.windows import BlockPlan
 from repro.core.streaming import (
@@ -188,7 +187,7 @@ def make_sharded_loss(
     fwd_out_specs = (P(), P(rows_axes), P())
     if filtering:
         fwd_out_specs = fwd_out_specs + (tmax_spec,)
-    fwd_sharded = shard_map(
+    fwd_sharded = jax.shard_map(
         _fwd_shard, mesh=mesh,
         in_specs=(h_spec, w_spec, y_spec),
         out_specs=fwd_out_specs,
@@ -223,7 +222,7 @@ def make_sharded_loss(
     bwd_in_specs = (h_spec, w_spec, y_spec, P(rows_axes), P(rows_axes))
     if filtering:
         bwd_in_specs = bwd_in_specs + (tmax_spec,)
-    bwd_sharded = shard_map(
+    bwd_sharded = jax.shard_map(
         _bwd_shard, mesh=mesh,
         in_specs=bwd_in_specs,
         out_specs=(h_spec, w_spec),
@@ -251,7 +250,7 @@ def make_sharded_loss(
                 return gbar * keep / jnp.maximum(count, 1.0)
             return gbar * keep
 
-        gamma = shard_map(
+        gamma = jax.shard_map(
             _gamma, mesh=mesh,
             in_specs=(P(rows_axes), P()), out_specs=P(rows_axes),
             check_vma=False,

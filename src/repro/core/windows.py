@@ -27,9 +27,11 @@ from __future__ import annotations
 
 import dataclasses
 
-# v5e: 16 MiB VMEM per core; keep ~45% headroom for double buffering +
+# v5e: Mosaic's default scoped-VMEM limit is 16 MiB of the core's 128 MiB;
+# plans keep ~45% headroom under the default for double buffering +
 # spills (Pallas pipelines input windows, so ~2x the W tile is resident).
 VMEM_BYTES = 16 * 1024 * 1024
+VMEM_PHYSICAL_BYTES = 128 * 1024 * 1024
 _DEFAULT_BUDGET = int(VMEM_BYTES * 0.55)
 
 _LANE = 128
@@ -58,6 +60,31 @@ def tile_bytes(bm: int, bv: int, d: int, in_bytes: int = 2) -> int:
     logits = bm * bv * 4
     state = 4 * bm * 4  # m, a, z_sum, z_tgt in f32
     return 2 * (h_tile + w_tile) + logits + state
+
+
+def bwd_tile_bytes(bm: int, bv: int, d: int, in_bytes: int = 2) -> int:
+    """Backward VMEM bytes of one grid step — the dW kernel's, the larger
+    of the two: double-buffered H/W tiles and f32 (bv, d) output block,
+    four (bm, 1) f32 row vectors (lane-padded to 128), and the
+    temporaries of the step (the f32 logit, probability and gradient
+    tiles, the gradient cast to the input dtype, an f32 (bm, d)
+    relayout)."""
+    h_tile = bm * d * in_bytes
+    w_tile = bv * d * in_bytes
+    out = bv * d * 4
+    rows = 4 * bm * _LANE * 4
+    temps = 4 * bm * bv * 4 + bm * d * 4
+    return 2 * (h_tile + w_tile + out + rows) + temps
+
+
+def scoped_vmem_limit(working_set_bytes: int) -> int:
+    """Mosaic scoped-VMEM limit for a kernel whose VMEM model is
+    `working_set_bytes`: twice the model, for what it leaves out (spills,
+    relayouts, the bf16 parts an f32-precision dot splits its operands
+    into), never below twice the compiler's 16 MiB default and never
+    above the v5e's physical VMEM less a reserve."""
+    want = max(2 * working_set_bytes, 2 * VMEM_BYTES)
+    return int(min(want, VMEM_PHYSICAL_BYTES - VMEM_BYTES))
 
 
 def choose_blocks(

@@ -18,17 +18,22 @@ TPU adaptation of the paper's CUDA design (DESIGN.md §2):
 Grid layouts (R = n_rows/bm, Vb = V_padded/bv):
 
   forward : grid=(R, Vb)  — vocab innermost, state scratch per row tile
-  dH      : grid=(R, Vb)  — vocab innermost, dH scratch per row tile
-  dW      : grid=(Vb, R)  — rows  innermost, dW scratch per vocab tile
+  dH      : grid=(R, Vb)  — vocab innermost, dH output block per row tile
+  dW      : grid=(Vb, R)  — rows  innermost, dW output block per vocab tile
 
 Gradient filtering (DESIGN.md §9): `fwd_stats(..., return_tile_stats=
 True)` additionally emits a per-(row-block, vocab-block) max-valid-logit
 statistic from the same online scan; `bwd_grads(..., tile_stats=...)`
 with `cfg.grad_filter_eps > 0` derives a sound skip mask from it
-(`core/filtering.py`) and runs the `_*_kernel_filtered` variants, which
-gate each tile's recompute + MXU accumulate on the mask delivered
-through (1, 1) BlockSpecs.  Without a mask the exact kernels run,
-bit-for-bit the pre-filter code.
+(`core/filtering.py`) and runs the kernels' filtered variants, which
+gate each tile's recompute + MXU accumulate on the mask, scalar-
+prefetched into SMEM.  Without a mask the exact kernels run, bit-for-bit
+the pre-filter code.
+
+Layouts follow Mosaic's tiling rule (a block's last two dims divide by
+(8, 128) or span the array): the tile statistic of one row block is a
+(1, num_v) lane vector resident across the vocab axis, and every kernel
+asks for the scoped VMEM its working set needs (`core/windows.py`).
 """
 
 from __future__ import annotations
@@ -42,10 +47,32 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.types import LossConfig
-from repro.core.windows import choose_blocks, BlockPlan
+from repro.core.windows import (BlockPlan, bwd_tile_bytes,
+                                choose_blocks, tile_bytes)
 from repro.kernels.pallas_utils import compiler_params, interpret_default
 
 _NEG_INF = float("-inf")
+
+
+def _mxu_dot(a, b, contract):
+    """`a . b` on the MXU with f32 accumulation, contracting dims
+    `contract` = (a's, b's).  Mosaic's default runs an f32 operand as one
+    bf16 pass (8 significant bits, truncated), so a dot with an f32
+    operand asks for the f32 (HIGHEST) matmul; bf16 operands take one
+    pass."""
+    precision = (jax.lax.Precision.HIGHEST
+                 if jnp.float32 in (a.dtype, b.dtype) else None)
+    return jax.lax.dot_general(
+        a, b, dimension_numbers=(contract, ((), ())), precision=precision,
+        preferred_element_type=jnp.float32)
+
+
+def _grad_dot(g, x, contract):
+    """The backward's f32 gradient tile `g` against the input tile `x`,
+    with `g` rounded to `x`'s dtype first: one bf16 pass for a bf16
+    model (its dH and dW are cast to bf16 anyway), the f32 matmul for
+    an f32 one."""
+    return _mxu_dot(g.astype(x.dtype), x, contract)
 
 
 def _tile_logits(h_tile, w_tile, cfg: LossConfig, scale_row=None):
@@ -59,11 +86,7 @@ def _tile_logits(h_tile, w_tile, cfg: LossConfig, scale_row=None):
     """
     if scale_row is not None:
         w_tile = w_tile.astype(h_tile.dtype)
-    z = jax.lax.dot_general(
-        h_tile, w_tile,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    z = _mxu_dot(h_tile, w_tile, ((1,), (1,)))
     if scale_row is not None:
         z = z * scale_row
     if cfg.logit_softcap is not None:
@@ -133,7 +156,11 @@ def _fwd_kernel(off_ref, y_ref, h_ref, w_ref,   # inputs (+ opt. scale)
         row = pl.program_id(0) * bm + jax.lax.broadcasted_iota(
             jnp.int32, (bm, 1), 0)
         live = (row < n_orig) & (y != cfg.ignore_index)
-        tmax_ref[0, 0] = jnp.max(jnp.where(live, z, _NEG_INF))
+        tile_max = jnp.max(jnp.max(jnp.where(live, z, _NEG_INF), axis=1,
+                                   keepdims=True), axis=0, keepdims=True)
+        # lane v of the row block's resident (1, num_v) stats vector
+        lane = jax.lax.broadcasted_iota(jnp.int32, tmax_ref.shape, 1)
+        tmax_ref[...] = jnp.where(lane == v, tile_max, tmax_ref[...])
 
     @pl.when(v == num_v - 1)
     def _epilogue():
@@ -193,8 +220,10 @@ def fwd_stats(
     out_shape = [jax.ShapeDtypeStruct((np_, 1), jnp.float32)] * 3
     out_specs = [pl.BlockSpec((bm, 1), lambda r, v: (r, 0))] * 3
     if return_tile_stats:
-        out_shape.append(jax.ShapeDtypeStruct((num_r, num_v), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, 1), lambda r, v: (r, v)))
+        out_shape.append(
+            jax.ShapeDtypeStruct((num_r, 1, num_v), jnp.float32))
+        out_specs.append(
+            pl.BlockSpec((None, 1, num_v), lambda r, v: (r, 0, 0)))
     kern = functools.partial(_fwd_kernel, cfg=cfg, valid=valid,
                              v_orig=v_orig, bv=bv, num_v=num_v,
                              n_orig=n, emit_stats=return_tile_stats,
@@ -217,12 +246,14 @@ def fwd_stats(
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bm, 1), jnp.float32) for _ in range(4)],
-        compiler_params=compiler_params(),
+        compiler_params=compiler_params(
+            tile_bytes(bm, bv, d, in_bytes=h.dtype.itemsize)),
         interpret=interpret,
+        name="fused_ce_fwd",
     )(*inputs)
     lse, ztgt, zsum = (o[:n, 0] for o in outs[:3])
     if return_tile_stats:
-        return lse, ztgt, zsum, outs[3]
+        return lse, ztgt, zsum, outs[3][:, 0, :]
     return lse, ztgt, zsum
 
 
@@ -235,9 +266,7 @@ def _grad_tile(h_tile, w_tile, y_tile, lse_tile, gamma_tile, pc_tile,
                v_start, col_offset, cfg: LossConfig, valid: int,
                v_orig: int):
     """g = Γ·(p·(1+2λ_z·lse) − (1−ε)·onehot − ε/valid) for one tile."""
-    z = jax.lax.dot_general(
-        h_tile, w_tile, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    z = _mxu_dot(h_tile, w_tile, ((1,), (1,)))
     if cfg.logit_softcap is not None:
         cap = jnp.float32(cfg.logit_softcap)
         zc = cap * jnp.tanh(z / cap)
@@ -256,107 +285,61 @@ def _grad_tile(h_tile, w_tile, y_tile, lse_tile, gamma_tile, pc_tile,
     return jnp.where(col_valid, g, 0.0)
 
 
-def _dh_kernel(off_ref, y_ref, lse_ref, gm_ref, pc_ref, h_ref, w_ref,
-               dh_ref, dh_sc,
-               *, cfg: LossConfig, valid: int, v_orig: int, bv: int,
-               num_v: int):
-    v = pl.program_id(1)
+def _dh_kernel(*refs, cfg: LossConfig, valid: int, v_orig: int, bv: int,
+               num_v: int, filtered: bool):
+    """dH for one row tile, accumulated over the sequential vocab axis
+    straight into the resident f32 output block.  `filtered` prepends the
+    scalar-prefetched skip mask (flat (num_r * num_v,) int32 in SMEM):
+    the tile recompute + MXU accumulate never run for masked tiles
+    (DESIGN.md §9); init stays unconditional."""
+    if filtered:
+        skip_ref, *refs = refs
+    off_ref, y_ref, lse_ref, gm_ref, pc_ref, h_ref, w_ref, dh_ref = refs
+    r, v = pl.program_id(0), pl.program_id(1)
 
     @pl.when(v == 0)
     def _init():
-        dh_sc[...] = jnp.zeros_like(dh_sc[...])
+        dh_ref[...] = jnp.zeros_like(dh_ref[...])
 
-    g = _grad_tile(h_ref[...], w_ref[...], y_ref[...], lse_ref[...],
-                   gm_ref[...], pc_ref[...], v * bv, off_ref[0, 0], cfg,
-                   valid, v_orig)
-    # dH_tile += g @ W_tile      (bm,bv)x(bv,d) on the MXU
-    dh_sc[...] += jax.lax.dot_general(
-        g, w_ref[...].astype(jnp.float32),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(v == num_v - 1)
-    def _epilogue():
-        dh_ref[...] = dh_sc[...]
-
-
-def _dw_kernel(off_ref, y_ref, lse_ref, gm_ref, pc_ref, h_ref, w_ref,
-               dw_ref, dw_sc,
-               *, cfg: LossConfig, valid: int, v_orig: int, bv: int,
-               num_r: int):
-    r = pl.program_id(1)
-
-    @pl.when(r == 0)
-    def _init():
-        dw_sc[...] = jnp.zeros_like(dw_sc[...])
-
-    v = pl.program_id(0)
-    g = _grad_tile(h_ref[...], w_ref[...], y_ref[...], lse_ref[...],
-                   gm_ref[...], pc_ref[...], v * bv, off_ref[0, 0], cfg,
-                   valid, v_orig)
-    # dW_tile += g^T @ H_tile    (bv,bm)x(bm,d) on the MXU
-    dw_sc[...] += jax.lax.dot_general(
-        g, h_ref[...].astype(jnp.float32),
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(r == num_r - 1)
-    def _epilogue():
-        dw_ref[...] = dw_sc[...]
-
-
-def _dh_kernel_filtered(skip_ref, off_ref, y_ref, lse_ref, gm_ref, pc_ref,
-                        h_ref, w_ref, dh_ref, dh_sc,
-                        *, cfg: LossConfig, valid: int, v_orig: int,
-                        bv: int, num_v: int):
-    """`_dh_kernel` with a per-(row-block, vocab-block) skip gate: the
-    tile recompute + MXU accumulate never run for masked tiles
-    (DESIGN.md §9); init/epilogue stay unconditional."""
-    v = pl.program_id(1)
-
-    @pl.when(v == 0)
-    def _init():
-        dh_sc[...] = jnp.zeros_like(dh_sc[...])
-
-    @pl.when(skip_ref[0, 0] == 0)
     def _accumulate():
         g = _grad_tile(h_ref[...], w_ref[...], y_ref[...], lse_ref[...],
                        gm_ref[...], pc_ref[...], v * bv, off_ref[0, 0],
                        cfg, valid, v_orig)
-        dh_sc[...] += jax.lax.dot_general(
-            g, w_ref[...].astype(jnp.float32),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        # dH_tile += g @ W_tile      (bm,bv)x(bv,d) on the MXU
+        dh_ref[...] += _grad_dot(g, w_ref[...], ((1,), (0,)))
 
-    @pl.when(v == num_v - 1)
-    def _epilogue():
-        dh_ref[...] = dh_sc[...]
+    if filtered:
+        pl.when(skip_ref[r * num_v + v] == 0)(_accumulate)
+    else:
+        _accumulate()
 
 
-def _dw_kernel_filtered(skip_ref, off_ref, y_ref, lse_ref, gm_ref, pc_ref,
-                        h_ref, w_ref, dw_ref, dw_sc,
-                        *, cfg: LossConfig, valid: int, v_orig: int,
-                        bv: int, num_r: int):
-    r = pl.program_id(1)
-    v = pl.program_id(0)   # hoisted: program_id can't be staged into when()
+def _dw_kernel(*refs, cfg: LossConfig, valid: int, v_orig: int, bv: int,
+               num_v: int, filtered: bool):
+    """dW for one vocab tile, accumulated over the sequential row axis
+    into the resident f32 output block; `filtered` as in `_dh_kernel`
+    (the same (num_r, num_v) mask, read transposed: this grid is
+    (v, r)-major)."""
+    if filtered:
+        skip_ref, *refs = refs
+    off_ref, y_ref, lse_ref, gm_ref, pc_ref, h_ref, w_ref, dw_ref = refs
+    v, r = pl.program_id(0), pl.program_id(1)
 
     @pl.when(r == 0)
     def _init():
-        dw_sc[...] = jnp.zeros_like(dw_sc[...])
+        dw_ref[...] = jnp.zeros_like(dw_ref[...])
 
-    @pl.when(skip_ref[0, 0] == 0)
     def _accumulate():
         g = _grad_tile(h_ref[...], w_ref[...], y_ref[...], lse_ref[...],
                        gm_ref[...], pc_ref[...], v * bv, off_ref[0, 0],
                        cfg, valid, v_orig)
-        dw_sc[...] += jax.lax.dot_general(
-            g, h_ref[...].astype(jnp.float32),
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        # dW_tile += g^T @ H_tile    (bv,bm)x(bm,d) on the MXU
+        dw_ref[...] += _grad_dot(g, h_ref[...], ((0,), (0,)))
 
-    @pl.when(r == num_r - 1)
-    def _epilogue():
-        dw_ref[...] = dw_sc[...]
+    if filtered:
+        pl.when(skip_ref[r * num_v + v] == 0)(_accumulate)
+    else:
+        _accumulate()
 
 
 def bwd_grads(
@@ -416,74 +399,41 @@ def bwd_grads(
     lse2, gm2, pc2 = lse[:, None], gamma[:, None], p_coeff[:, None]
 
     filtered = skip_mask is not None
+    prefetch = ()
     if filtered:
         if skip_mask.shape != (num_r, num_v):
             raise ValueError(
                 f"skip mask shape {skip_mask.shape} does not match the "
                 f"backward grid {(num_r, num_v)} of plan {plan.shape}")
-        skip = skip_mask.astype(jnp.int32)
+        # scalar-prefetched into SMEM: the kernels branch on it per tile
+        prefetch = (skip_mask.astype(jnp.int32).reshape(-1),)
+    kw = dict(cfg=cfg, valid=valid, v_orig=v_orig, bv=bv, num_v=num_v,
+              filtered=filtered)
+    args = prefetch + (off, y2, lse2, gm2, pc2, h, w)
+    vmem = bwd_tile_bytes(bm, bv, d, in_bytes=h.dtype.itemsize)
 
-    row_in = lambda r, v: (r, 0)
-    dh_in_specs = [
-        pl.BlockSpec((1, 1), lambda r, v: (0, 0)),      # col offset
-        pl.BlockSpec((bm, 1), row_in),                  # y
-        pl.BlockSpec((bm, 1), row_in),                  # lse
-        pl.BlockSpec((bm, 1), row_in),                  # gamma
-        pl.BlockSpec((bm, 1), row_in),                  # p_coeff
-        pl.BlockSpec((bm, d), row_in),                  # h
-        pl.BlockSpec((bv, d), lambda r, v: (v, 0)),     # w
-    ]
-    dh_args = (off, y2, lse2, gm2, pc2, h, w)
-    if filtered:
-        dh_kern = functools.partial(_dh_kernel_filtered, cfg=cfg,
-                                    valid=valid, v_orig=v_orig, bv=bv,
-                                    num_v=num_v)
-        dh_in_specs.insert(0, pl.BlockSpec((1, 1), lambda r, v: (r, v)))
-        dh_args = (skip,) + dh_args
-    else:
-        dh_kern = functools.partial(_dh_kernel, cfg=cfg, valid=valid,
-                                    v_orig=v_orig, bv=bv, num_v=num_v)
-    dh = pl.pallas_call(
-        dh_kern,
-        grid=(num_r, num_v),
-        in_specs=dh_in_specs,
-        out_specs=pl.BlockSpec((bm, d), row_in),
-        out_shape=jax.ShapeDtypeStruct((np_, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bm, d), jnp.float32)],
-        compiler_params=compiler_params(),
-        interpret=interpret,
-    )(*dh_args)
+    def call(kernel, name, grid, row_axis, out_rows, out_block_rows):
+        # index maps see the grid indices, then the prefetched mask ref;
+        # the output tile follows the outer (parallel) grid axis
+        rows = lambda *i: (i[row_axis], 0)
+        cols = lambda *i: (i[1 - row_axis], 0)
+        in_specs = ([pl.BlockSpec((1, 1), lambda *i: (0, 0))]   # col offset
+                    + [pl.BlockSpec((bm, 1), rows)] * 4  # y lse gamma p_coeff
+                    + [pl.BlockSpec((bm, d), rows),             # h
+                       pl.BlockSpec((bv, d), cols)])            # w
+        return pl.pallas_call(
+            functools.partial(kernel, **kw),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(prefetch), grid=grid,
+                in_specs=in_specs,
+                out_specs=pl.BlockSpec((out_block_rows, d),
+                                       lambda *i: (i[0], 0))),
+            out_shape=jax.ShapeDtypeStruct((out_rows, d), jnp.float32),
+            compiler_params=compiler_params(vmem),
+            interpret=interpret,
+            name=name,
+        )(*args)
 
-    row_in2 = lambda v, r: (r, 0)
-    dw_in_specs = [
-        pl.BlockSpec((1, 1), lambda v, r: (0, 0)),      # col offset
-        pl.BlockSpec((bm, 1), row_in2),                 # y
-        pl.BlockSpec((bm, 1), row_in2),                 # lse
-        pl.BlockSpec((bm, 1), row_in2),                 # gamma
-        pl.BlockSpec((bm, 1), row_in2),                 # p_coeff
-        pl.BlockSpec((bm, d), row_in2),                 # h
-        pl.BlockSpec((bv, d), lambda v, r: (v, 0)),     # w
-    ]
-    dw_args = (off, y2, lse2, gm2, pc2, h, w)
-    if filtered:
-        dw_kern = functools.partial(_dw_kernel_filtered, cfg=cfg,
-                                    valid=valid, v_orig=v_orig, bv=bv,
-                                    num_r=num_r)
-        # same (num_r, num_v) mask; the dw grid is (v, r)-major
-        dw_in_specs.insert(0, pl.BlockSpec((1, 1), lambda v, r: (r, v)))
-        dw_args = (skip,) + dw_args
-    else:
-        dw_kern = functools.partial(_dw_kernel, cfg=cfg, valid=valid,
-                                    v_orig=v_orig, bv=bv, num_r=num_r)
-    dw = pl.pallas_call(
-        dw_kern,
-        grid=(num_v, num_r),
-        in_specs=dw_in_specs,
-        out_specs=pl.BlockSpec((bv, d), lambda v, r: (v, 0)),
-        out_shape=jax.ShapeDtypeStruct((vp, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bv, d), jnp.float32)],
-        compiler_params=compiler_params(),
-        interpret=interpret,
-    )(*dw_args)
-
+    dh = call(_dh_kernel, "fused_ce_dh", (num_r, num_v), 0, np_, bm)
+    dw = call(_dw_kernel, "fused_ce_dw", (num_v, num_r), 1, vp, bv)
     return dh[:n], dw[:v_orig]

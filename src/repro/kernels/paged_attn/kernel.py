@@ -230,6 +230,7 @@ def pallas_paged_attention(
         out_shape=jax.ShapeDtypeStruct((b, rows_pad, hd), jnp.float32),
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="paged_attn",
     )(table.astype(jnp.int32), lens.astype(jnp.int32), q_r, *inputs)
     out = out[:, :rows].reshape(b, nkv, g, tq, hd)
     return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(

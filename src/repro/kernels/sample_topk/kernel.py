@@ -260,6 +260,7 @@ def topk_scores(
         scratch_shapes=scratch,
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="sample_topk",
     )(*inputs)
     vals, idxs = out[0][:n, :k], out[1][:n, :k]
     if return_lse:

@@ -209,5 +209,6 @@ def score_stats(
                         pltpu.VMEM((bm, kp), jnp.float32)],
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="score_tokens",
     )(*inputs)
     return lse[:n, 0], zt[:n, :p_cand]

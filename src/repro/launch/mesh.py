@@ -3,7 +3,17 @@ never touches jax device state)."""
 
 from __future__ import annotations
 
-from repro.compat import make_mesh
+from typing import Sequence
+
+import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape: Sequence[int], axes: Sequence[str]) -> jax.sharding.Mesh:
+    # the sharding rules place arrays with PartitionSpecs, i.e. GSPMD's
+    # Auto axes (jax.make_mesh defaults to Explicit)
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -11,9 +21,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2 pods x 256 = 512 chips ((pod, data, model) = (2,16,16))."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
-    """Small mesh over the host's real/forced devices (tests, examples)."""
-    return make_mesh((data, model), ("data", "model"))
+    """(data, model) mesh over this host's devices: its chips on a TPU
+    host, forced host devices on the CPU (tests, examples)."""
+    return _mesh((data, model), ("data", "model"))

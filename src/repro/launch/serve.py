@@ -29,19 +29,30 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Any, NamedTuple
 
 import jax
 import numpy as np
 
 from repro import obs
 from repro.configs.base import with_mtp
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.registry import get_arch, init_params
 from repro.serve import (ServeConfig, Engine, ContinuousScheduler,
                          SpecConfig, SpecEngine, SelfSpecEngine,
                          PagedEngine, PagedSelfSpecEngine)
 
 
-def main(argv=None):
+class ServeRun(NamedTuple):
+    """What one run leaves: the per-request outputs (generated tokens,
+    or eval scores), the engine that served them and its scheduler."""
+    out: np.ndarray
+    engine: Any
+    scheduler: ContinuousScheduler
+
+
+def run(argv=None) -> ServeRun:
+    """Parse `argv` as the command line, serve, and return the run."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--reduced", action="store_true")
@@ -288,8 +299,14 @@ def main(argv=None):
                                (0, args.max_new - len(results[r])))
                         for r in rids])
         print("[serve] sample row:", out[0][:16])
-    return out
+    return ServeRun(out, eng, sched)
+
+
+def main(argv=None):
+    """Serve; returns the per-request outputs."""
+    return run(argv).out
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

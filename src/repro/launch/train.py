@@ -3,9 +3,13 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-7b --reduced \
         --steps 50 --global-batch 8 --seq-len 128 --ckpt-dir /tmp/ckpt
 
-Use --devices D,M to force a local (data, model) mesh over
---xla_force_host_platform_device_count devices (set XLA_FLAGS yourself for
-that case); by default runs single-device.
+``--devices D,M`` trains on a (data, model) mesh of the host's devices:
+its real chips on a TPU host (``--devices 1,4`` splits the model and the
+vocabulary over four chips), forced host devices on the CPU (set
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before jax starts).
+Without it, one device.  ``--loss-impl auto`` (the default) runs the Pallas
+fused-CE kernels on a TPU and the streaming loss elsewhere; ``sharded``
+needs ``--devices``.
 
 ``--stats-json [PATH]`` dumps the logged step history as JSON;
 ``--metrics-json [PATH]`` enables `repro.obs` and dumps step-time /
@@ -18,6 +22,7 @@ from __future__ import annotations
 
 import argparse
 import logging
+from typing import Any, List, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +32,7 @@ from repro.checkpoint import Checkpointer
 from repro.configs.base import TuningConfig, with_mtp
 from repro.data import DataConfig, SyntheticLM, ShardedLoader
 from repro.distributed.fault import PreemptionHandler, StragglerMonitor
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models.registry import get_arch
 from repro.sharding.rules import AxisRules
@@ -35,7 +41,16 @@ from repro.train import (TrainConfig, build_train_step, train_loop,
 from repro.train.step import make_tuning_prewarm
 
 
-def main(argv=None):
+class TrainRun(NamedTuple):
+    """What one run leaves: the final state, the logged history and the
+    jitted step it ran (``step.lower(state, batch)`` gives its program)."""
+    state: Any
+    history: List[Tuple[int, dict]]
+    step: Any
+
+
+def run(argv=None) -> TrainRun:
+    """Parse `argv` as the command line, train, and return the run."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-7b")
     ap.add_argument("--reduced", action="store_true",
@@ -46,8 +61,9 @@ def main(argv=None):
     ap.add_argument("--lr", type=float, default=3e-3)
     ap.add_argument("--optimizer", default="adamw",
                     choices=("adamw", "adafactor"))
-    ap.add_argument("--loss-impl", default="streaming",
-                    choices=("streaming", "pallas", "canonical", "sharded"))
+    ap.add_argument("--loss-impl", default="auto",
+                    choices=("auto", "streaming", "pallas", "canonical",
+                             "sharded"))
     ap.add_argument("--grad-accum", type=int, default=1)
     ap.add_argument("--grad-filter-eps", type=float, default=0.0,
                     help="gradient-filtered backward: skip vocab tiles "
@@ -71,7 +87,8 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--devices", default=None,
-                    help="D,M local mesh (needs forced host devices)")
+                    help="D,M (data, model) mesh over the host's chips on "
+                         "a TPU, over forced host devices on the CPU")
     ap.add_argument("--stats-json", nargs="?", const="-", default=None,
                     metavar="PATH",
                     help="dump the logged step history (loss, step time) "
@@ -171,8 +188,15 @@ def main(argv=None):
     if args.trace_out is not None:
         obs.export.write_trace(obs.get_tracer(), args.trace_out,
                                fmt=args.trace_format, tag="train")
-    return state, history
+    return TrainRun(state, history, jstep)
+
+
+def main(argv=None):
+    """Train; returns (final state, logged history)."""
+    res = run(argv)
+    return res.state, res.history
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

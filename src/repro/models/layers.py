@@ -65,8 +65,7 @@ def embed_lookup(table, tokens, shard=None):
     def local(tab_l, tok_l):
         return tab_l[tok_l]
 
-    from repro.compat import shard_map
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, "model"), tok_spec),
         out_specs=out_spec, check_vma=False)(table, tokens)

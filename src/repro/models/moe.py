@@ -182,8 +182,7 @@ def moe_layer(
         return jax.lax.psum(y, "model")
 
     w_spec = P("model", None, None)
-    from repro.compat import shard_map
-    out = shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=([w_spec] * len(w_names), row3, row2, row3),
         out_specs=row3, check_vma=False,

@@ -111,10 +111,14 @@ def train_loop(
 
 def resume_or_init(checkpointer: Optional[Checkpointer], init_fn,
                    rng, shardings=None):
-    """Restore the latest checkpoint if present, else init fresh."""
+    """Restore the latest checkpoint if present, else init fresh —
+    straight into `shardings` when given, so no device ever holds more
+    than its own shard of the state."""
     if checkpointer is not None and checkpointer.latest_step() is not None:
         example = jax.eval_shape(init_fn, rng)
         state, step = checkpointer.restore(example, shardings=shardings)
         log.info("resumed from step %d", step)
         return state
+    if shardings is not None:
+        return jax.jit(init_fn, out_shardings=shardings)(rng)
     return init_fn(rng)

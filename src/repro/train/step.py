@@ -2,10 +2,15 @@
 
 The loss is the paper's fused projection+CE.  Implementation selection:
 
-  'streaming' / 'pallas' / 'canonical'   local (per-device full vocab)
+  'auto' / 'streaming' / 'pallas' / 'canonical'
+                                         local (per-device full vocab);
+                                         'auto' is 'pallas' on a TPU
   'sharded'                              shard_map vocab-TP + row-DP
                                          (paper §3.2.2; '2d' layout)
   'sharded_sp'                           paper-faithful SP->TP gather
+
+The sharded impls need a mesh, and stream each device's panel with the
+same local kernel 'auto' picks.
 
 Gradient accumulation: the global batch is split into `grad_accum`
 microbatches scanned sequentially, grads accumulated in f32.  Combined
@@ -24,6 +29,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import Arch, TuningConfig
 from repro.core import fused_cross_entropy, LossConfig
+from repro.core.fused_ce import default_impl
 from repro.core.windows import BlockPlan
 from repro.core.sharded import make_sharded_loss
 from repro.models.registry import forward_hidden
@@ -130,7 +136,10 @@ def build_loss_fn(arch: Arch, tc: TrainConfig,
     n_mtp = arch.mtp.n_heads
     mtp_w = arch.mtp.resolved_weights()
 
-    use_sharded = tc.loss_impl in ("sharded", "sharded_sp") and mesh is not None
+    use_sharded = tc.loss_impl in ("sharded", "sharded_sp")
+    if use_sharded and mesh is None:
+        raise ValueError(f"loss_impl={tc.loss_impl!r} shards the loss over "
+                         "a mesh; pass AxisRules with a mesh (--devices D,M)")
     rows_axes = tuple(a for a in ("pod", "data")
                       if a in mesh.axis_names) if use_sharded else ()
     layout = "sp_gather" if tc.loss_impl == "sharded_sp" else "2d"
@@ -149,7 +158,7 @@ def build_loss_fn(arch: Arch, tc: TrainConfig,
                 d, dtype)
             sharded_cache[key] = make_sharded_loss(
                 mesh, lcfg, rows_axes=rows_axes, vocab_axis="model",
-                layout=layout, impl="streaming", plan=plan)
+                layout=layout, impl=default_impl(), plan=plan)
         return sharded_cache[key]
 
     def loss_fn(params, batch):
@@ -169,9 +178,7 @@ def build_loss_fn(arch: Arch, tc: TrainConfig,
             def ce_of(r, y):
                 return sfn(r, w, y)
         else:
-            impl = (tc.loss_impl
-                    if tc.loss_impl not in ("sharded", "sharded_sp")
-                    else "streaming")
+            impl = tc.loss_impl
             plan = None
             if impl in ("streaming", "pallas", "auto"):
                 # resolved ONCE; every horizon streams the same panel shape

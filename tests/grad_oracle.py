@@ -109,7 +109,11 @@ def max_abs_dev(ga, gb):
         for a, b in zip(ga, gb))
 
 
-def assert_grads_close(ga, gb, rtol=3e-4, atol=1e-5):
+# the tolerance every gradient comparison against the oracle is held to
+GRAD_RTOL, GRAD_ATOL = 3e-4, 1e-5
+
+
+def assert_grads_close(ga, gb, rtol=GRAD_RTOL, atol=GRAD_ATOL):
     np.testing.assert_allclose(np.asarray(ga[0], np.float32),
                                np.asarray(gb[0], np.float32),
                                rtol=rtol, atol=atol)

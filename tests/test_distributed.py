@@ -18,8 +18,8 @@ def _run(body: str, timeout=560):
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
-        mesh = make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_local_mesh
+        mesh = make_local_mesh(2, 4)
     """) + textwrap.dedent(body)
     env = dict(os.environ, PYTHONPATH=os.path.join(_ROOT, "src"))
     res = subprocess.run([sys.executable, "-c", script], env=env,
@@ -109,7 +109,7 @@ def test_compressed_psum_and_elastic_reshard():
         res = {"w": jnp.zeros((2, 16, 8))}
         def sync(g, r):
             return compressed_psum_tree(g, r, "data")
-        f = shard_map(sync, mesh=mesh,
+        f = jax.shard_map(sync, mesh=mesh,
                           in_specs=({"w": P("data", None, None)},
                                     {"w": P("data", None, None)}),
                           out_specs=({"w": P("data", None, None)},
@@ -129,7 +129,7 @@ def test_compressed_psum_and_elastic_reshard():
         params = {"w": jax.random.normal(jax.random.PRNGKey(1), (16, 32))}
         r1 = AxisRules(mesh=mesh)
         p1 = reshard(params, param_shardings(params, r1))
-        mesh2 = make_mesh((4, 2), ("data", "model"))
+        mesh2 = make_local_mesh(4, 2)
         r2 = AxisRules(mesh=mesh2)
         p2 = reshard(p1, param_shardings(params, r2))
         np.testing.assert_allclose(np.asarray(p2["w"]),

@@ -34,6 +34,33 @@ def test_train_cli_end_to_end(tmp_path):
     assert int(jax.device_get(state2["step"])) == 8
 
 
+def test_train_cli_sharded_loss_needs_a_mesh():
+    """--loss-impl sharded without --devices used to train with the
+    streaming loss instead; it must refuse."""
+    from repro.launch.train import main
+    with pytest.raises(ValueError, match="mesh"):
+        main(["--arch", "qwen3-0.6b", "--reduced", "--steps", "1",
+              "--global-batch", "2", "--seq-len", "16",
+              "--loss-impl", "sharded"])
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The cache stays where JAX_COMPILATION_CACHE_DIR says; otherwise it
+    is one fixed directory of the checkout."""
+    from repro.launch import compile_cache
+    seen = []
+    monkeypatch.setattr(compile_cache.jax.config, "update",
+                        lambda name, value: seen.append((name, value)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.use_compile_cache() == "/elsewhere/cache"
+    assert seen == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = compile_cache.use_compile_cache()
+    assert path == compile_cache.use_compile_cache()
+    assert path.endswith(".jax_cache")
+    assert seen == [("jax_compilation_cache_dir", path)] * 2
+
+
 def test_serve_cli(capsys):
     from repro.launch.serve import main
     out = main(["--arch", "qwen3-0.6b", "--reduced", "--batch", "2",

@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.compat import make_mesh
+from repro.launch.mesh import make_local_mesh
 from repro.models.registry import empty_serve_caches, get_arch, init_params
 from repro.serve.kvpool import paged_config
 from repro.serve.partition import batch_specs, cache_specs
@@ -32,7 +32,7 @@ def _arch(arch_id, scanned):
 
 
 def _rules():
-    return AxisRules(mesh=make_mesh((1, 1), ("data", "model")))
+    return AxisRules(mesh=make_local_mesh(1, 1))
 
 
 def _at(spec, i):
