@@ -1,0 +1,8 @@
+"""Device time of the ops under the program's `mlp` scope per train
+step, per chip: forward, recompute and backward, each beside the sum
+(`bench/scope_reduce.py`)."""
+from bench import scope_reduce
+
+
+def read(ctx):
+    return scope_reduce.ms_per_step(ctx, "mlp")

@@ -12,10 +12,11 @@ fused-CE kernels on a TPU and the streaming loss elsewhere; ``sharded``
 needs ``--devices``.
 
 ``--stats-json [PATH]`` dumps the logged step history as JSON;
-``--metrics-json [PATH]`` enables `repro.obs` and dumps step-time /
-tokens-per-sec / loss instruments; ``--trace-out PATH`` records a
-``train.step`` span per step (bridged to ``StepTraceAnnotation`` so
-host spans line up with device profiles) — see DESIGN.md §11.
+``--metrics-json [PATH]`` enables `repro.obs` and dumps the step-time,
+loss and ``compile.*`` instruments; ``--trace-out PATH`` records a
+``train.step`` span per step holding ``train.feed``, ``train.dispatch``
+and ``train.wait`` (bridged to the profiler's annotations so host spans
+line up with device profiles) — see DESIGN.md §11.
 """
 
 from __future__ import annotations
@@ -96,12 +97,14 @@ def run(argv=None) -> TrainRun:
     ap.add_argument("--metrics-json", nargs="?", const="-", default=None,
                     metavar="PATH",
                     help="enable the repro.obs registry and dump every "
-                         "instrument's snapshot as JSON (stdout when "
-                         "PATH is omitted)")
+                         "instrument's snapshot, the compile.* counters "
+                         "included, as JSON (stdout when PATH is "
+                         "omitted)")
     ap.add_argument("--trace-out", default=None, metavar="PATH",
-                    help="enable train.step span tracing (with "
-                         "StepTraceAnnotation bridging) and write the "
-                         "trace to PATH")
+                    help="enable span tracing of each train.step and "
+                         "its train.feed / train.dispatch / train.wait "
+                         "(bridged to jax.profiler annotations) and write "
+                         "the trace to PATH")
     ap.add_argument("--trace-format", default="chrome",
                     choices=("chrome", "jsonl"),
                     help="trace export format for --trace-out")

@@ -139,21 +139,30 @@ def init_params(key, cfg: TransformerConfig) -> Dict[str, Any]:
 
 def apply_block(p, x, cfg: TransformerConfig, *, cache=None, shard=None,
                 decode=False, prefill_ext=False):
-    """Pre-norm block; returns (x, aux, new_cache)."""
+    """Pre-norm block; returns (x, aux, new_cache).  Each layer opens its
+    scope of `repro.obs.SCOPES` here, at its call site."""
     acfg = cfg.attn_config()
-    h, new_cache = A.attention_layer(
-        p["attn"], L.rmsnorm(p["ln_attn"], x, cfg.norm_eps), acfg,
-        cache=cache, shard=shard, decode=decode, prefill_ext=prefill_ext)
+    with jax.named_scope("norm"):
+        xn = L.rmsnorm(p["ln_attn"], x, cfg.norm_eps)
+    with jax.named_scope("attn"):
+        h, new_cache = A.attention_layer(
+            p["attn"], xn, acfg, cache=cache, shard=shard, decode=decode,
+            prefill_ext=prefill_ext)
     x = x + h
-    xn = L.rmsnorm(p["ln_mlp"], x, cfg.norm_eps)
+    with jax.named_scope("norm"):
+        xn = L.rmsnorm(p["ln_mlp"], x, cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
     if cfg.is_moe:
-        mo, aux = M.moe_layer(p["moe"], xn, cfg.moe_config(), shard=shard)
+        with jax.named_scope("moe"):
+            mo, aux = M.moe_layer(p["moe"], xn, cfg.moe_config(),
+                                  shard=shard)
         if cfg.dense_ff_residual:
-            mo = mo + L.mlp(p["mlp"], xn)
+            with jax.named_scope("mlp"):
+                mo = mo + L.mlp(p["mlp"], xn)
         x = x + mo
     else:
-        y = L.mlp(p["mlp"], xn)
+        with jax.named_scope("mlp"):
+            y = L.mlp(p["mlp"], xn)
         if shard is not None:
             y = shard(y, "batch", "seq", "embed")
         x = x + y
@@ -176,8 +185,9 @@ def forward(
     cache per row instead of prefilling it — speculative verification,
     or (with ``prefill_ext=True``) the paged suffix-only prefill.
     """
-    x = L.embed_lookup(params["embed"]["table"], tokens,
-                   shard=shard).astype(_cdt(cfg))
+    with jax.named_scope("embed"):
+        x = L.embed_lookup(params["embed"]["table"], tokens,
+                           shard=shard).astype(_cdt(cfg))
     if frontend_embeds is not None:
         x = jnp.concatenate([frontend_embeds.astype(x.dtype), x], axis=1)
     if shard is not None:
@@ -193,38 +203,40 @@ def forward(
         return apply_block(p, x, cfg, cache=cache, shard=shard,
                            decode=decode, prefill_ext=prefill_ext)
 
-    if cfg.scan_layers:
-        if caches is None:
-            def scan_body(carry, p):
-                x, aux_sum = carry
-                x, aux, _ = block_fn(p, x, None)
-                return (x, aux_sum + aux), None
+    with jax.named_scope("blocks"):
+        if cfg.scan_layers:
+            if caches is None:
+                def scan_body(carry, p):
+                    x, aux_sum = carry
+                    x, aux, _ = block_fn(p, x, None)
+                    return (x, aux_sum + aux), None
 
-            (x, aux), _ = jax.lax.scan(
-                scan_body, (x, jnp.zeros((), jnp.float32)),
-                params["blocks"])
-            new_caches = None
+                (x, aux), _ = jax.lax.scan(
+                    scan_body, (x, jnp.zeros((), jnp.float32)),
+                    params["blocks"])
+                new_caches = None
+            else:
+                def scan_body(carry, layer_in):
+                    x, aux_sum = carry
+                    p, cache = layer_in
+                    x, aux, new_cache = block_fn(p, x, cache)
+                    return (x, aux_sum + aux), new_cache
+
+                (x, aux), new_caches = jax.lax.scan(
+                    scan_body, (x, jnp.zeros((), jnp.float32)),
+                    (params["blocks"], caches))
         else:
-            def scan_body(carry, layer_in):
-                x, aux_sum = carry
-                p, cache = layer_in
-                x, aux, new_cache = block_fn(p, x, cache)
-                return (x, aux_sum + aux), new_cache
+            aux = jnp.zeros((), jnp.float32)
+            new_caches = [] if caches is not None else None
+            for i, p in enumerate(params["blocks"]):
+                c = caches[i] if caches is not None else None
+                x, a, nc = block_fn(p, x, c)
+                aux = aux + a
+                if caches is not None:
+                    new_caches.append(nc)
 
-            (x, aux), new_caches = jax.lax.scan(
-                scan_body, (x, jnp.zeros((), jnp.float32)),
-                (params["blocks"], caches))
-    else:
-        aux = jnp.zeros((), jnp.float32)
-        new_caches = [] if caches is not None else None
-        for i, p in enumerate(params["blocks"]):
-            c = caches[i] if caches is not None else None
-            x, a, nc = block_fn(p, x, c)
-            aux = aux + a
-            if caches is not None:
-                new_caches.append(nc)
-
-    x = L.rmsnorm(params["ln_f"], x, cfg.norm_eps)
+    with jax.named_scope("norm"):
+        x = L.rmsnorm(params["ln_f"], x, cfg.norm_eps)
     return x, aux, new_caches
 
 

@@ -20,11 +20,19 @@ used by benches and tests:
     with obs.capture(trace=True) as (reg, tracer):
         eng = PagedEngine(...)
         ...                              # globals restored on exit
+
+Two more seams live here.  :data:`SCOPES` names the model's layers
+inside compiled programs: each layer boundary opens one
+``jax.named_scope`` of that tuple at its call site, so every device op
+carries its layer in its ``op_name`` metadata.  And `enable` installs,
+once per process, `jax.monitoring` listeners that count compilations
+into whichever registry is current (``compile.*``).
 """
 
 from __future__ import annotations
 
 import contextlib
+import threading
 import time
 from typing import Callable, Tuple
 
@@ -41,13 +49,62 @@ __all__ = [
     "Span", "Tracer", "NullTracer", "NULL_TRACER",
     "chrome_trace_events", "read_jsonl", "request_coverage",
     "get_registry", "get_tracer", "set_registry", "set_tracer",
-    "enable", "disable", "capture",
+    "enable", "disable", "capture", "SCOPES", "COMPILE_COUNTERS",
     "export", "metrics", "trace",
 ]
 
 # process defaults: disabled until someone opts in
 _registry: Registry = Registry(enabled=False)
 _tracer = NULL_TRACER
+
+# The layer scopes of a model's programs (DESIGN.md §11.1), outermost
+# first.  Each is a `jax.named_scope` opened at the call site of one
+# layer boundary; an op's layer is the innermost of these in its
+# `op_name`.  JAX adds `rematted_computation` to ops that a checkpoint
+# recomputes in the backward pass.
+SCOPES = ("embed", "blocks", "norm", "attn", "mlp", "moe", "loss",
+          "optimizer")
+
+# jax.monitoring events -> the compile.* counters they feed.  The backend
+# compile event fires once for each executable JAX builds, compiled by
+# XLA or loaded from the persistent cache, and its duration covers either.
+_COUNTED = {
+    "/jax/core/compile/backend_compile_duration": "compile.backend_compiles",
+    "/jax/compilation_cache/cache_hits": "compile.cache_hits",
+    "/jax/compilation_cache/cache_misses": "compile.cache_misses",
+}
+_TIMED = {
+    "/jax/core/compile/backend_compile_duration": "compile.backend_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "compile.cache_load_s",
+}
+COMPILE_COUNTERS = tuple(_COUNTED.values()) + tuple(_TIMED.values())
+_listeners_lock = threading.Lock()
+_listeners_installed = False
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event in _COUNTED:
+        _registry.counter(_COUNTED[event]).inc()
+
+
+def _on_duration(event: str, secs: float, **_kw) -> None:
+    _on_event(event)
+    if event in _TIMED:
+        _registry.counter(_TIMED[event]).inc(secs)
+
+
+def _install_compile_listeners() -> None:
+    """Register the `jax.monitoring` listeners behind ``compile.*``, once
+    per process (`enable` calls this).  They feed the registry current
+    when a compile happens, so a disabled registry records nothing."""
+    global _listeners_installed
+    with _listeners_lock:
+        if _listeners_installed:
+            return
+        import jax.monitoring
+        jax.monitoring.register_event_listener(_on_event)
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _listeners_installed = True
 
 
 def get_registry() -> Registry:
@@ -81,12 +138,16 @@ def enable(trace: bool = False,
 
     Returns ``(registry, tracer)`` — the tracer is :data:`NULL_TRACER`
     when tracing stays off.  Call BEFORE constructing the engines /
-    schedulers / pools you want instrumented."""
+    schedulers / pools you want instrumented.  The ``compile.*``
+    counters start at 0 in the new registry."""
     reg = Registry(enabled=True)
+    for name in COMPILE_COUNTERS:
+        reg.counter(name)
     tr = Tracer(clock=clock, jax_annotate=jax_annotate) if trace \
         else NULL_TRACER
     set_registry(reg)
     set_tracer(tr)
+    _install_compile_listeners()
     return reg, tr
 
 

@@ -19,8 +19,7 @@ a unit suffix for measurements (``_s``, ``_us``, ``_bytes``) and a
 A **disabled** :class:`Registry` hands every caller the same shared
 :data:`NULL_METRIC` no-op instrument and records nothing — instrument
 construction in a disabled process allocates zero record objects, which
-is what keeps always-on call sites free (``bench_obs --smoke`` holds the
-enabled path under 2% tokens/sec as well).
+is what keeps always-on call sites free.
 """
 
 from __future__ import annotations

@@ -42,6 +42,12 @@ def train_loop(
     intended use is block-plan autotuning (`train.step.make_tuning_prewarm`)
     so kernel trial timing happens once here, outside the recorded per-step
     timings; its wall time is logged separately.
+
+    Each step is a `train.step` span (a `StepTraceAnnotation` when the
+    tracer bridges to the profiler) holding `train.feed` (the loader's
+    next batch), `train.dispatch` (the `step_fn` call) and `train.wait`
+    (the wait on the loss); logging and checkpoint saves are
+    `train.log` and `train.checkpoint`.
     """
     preemption = (preemption or PreemptionHandler()).install()
     straggler = straggler or StragglerMonitor()
@@ -52,8 +58,6 @@ def train_loop(
     tracer = obs.get_tracer()
     m_step_t = reg.histogram("train.step_time_s",
                              help="wall-clock per optimizer step")
-    m_tps = reg.gauge("train.tokens_per_sec",
-                      help="tokens consumed per second, last step")
     m_loss = reg.gauge("train.loss", help="loss at last logged step")
     m_steps = reg.counter("train.steps_total", help="optimizer steps run")
     m_tokens = reg.counter("train.tokens_total",
@@ -69,11 +73,14 @@ def train_loop(
     for i in range(start_step, num_steps):
         t0 = time.perf_counter()
         with tracer.step_span("train.step", i):
-            batch = next(it)
-            state, metrics = step_fn(state, batch)
+            with tracer.span("train.feed"):
+                batch = next(it)
+            with tracer.span("train.dispatch"):
+                state, metrics = step_fn(state, batch)
             # block for accurate step timing (and to surface async
             # errors here)
-            jax.block_until_ready(metrics["loss"])
+            with tracer.span("train.wait"):
+                jax.block_until_ready(metrics["loss"])
         dt = time.perf_counter() - t0
         straggler.record(i, dt)
         m_step_t.observe(dt)
@@ -82,23 +89,24 @@ def train_loop(
             if isinstance(batch, dict) else 0
         if n_tok:
             m_tokens.inc(n_tok)
-            m_tps.set(n_tok / dt if dt > 0 else 0.0)
 
         if (i + 1) % log_every == 0 or i == start_step:
-            m = {k: float(np.asarray(jax.device_get(v)))
-                 for k, v in metrics.items()}
-            m["step_time_s"] = dt
-            if "loss" in m:
-                m_loss.set(m["loss"])
-            history.append((i, m))
-            log.info("step %d: %s", i,
-                     {k: round(v, 5) for k, v in m.items()})
-            if metrics_hook:
-                metrics_hook(i, m)
+            with tracer.span("train.log"):
+                m = {k: float(np.asarray(jax.device_get(v)))
+                     for k, v in metrics.items()}
+                m["step_time_s"] = dt
+                if "loss" in m:
+                    m_loss.set(m["loss"])
+                history.append((i, m))
+                log.info("step %d: %s", i,
+                         {k: round(v, 5) for k, v in m.items()})
+                if metrics_hook:
+                    metrics_hook(i, m)
 
         if checkpointer and ((i + 1) % checkpoint_every == 0
                              or preemption.should_stop):
-            checkpointer.save_async(i + 1, state)
+            with tracer.span("train.checkpoint"):
+                checkpointer.save_async(i + 1, state)
 
         if preemption.should_stop:
             log.warning("preempted at step %d — checkpoint flushed", i)

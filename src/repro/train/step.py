@@ -168,6 +168,12 @@ def build_loss_fn(arch: Arch, tc: TrainConfig,
                                                return_heads=True)
         else:
             h, aux, _ = forward_hidden(arch, params, batch, shard=shard)
+            head_h = None
+        # the scope holds the fused_ce kernels (repro.obs.SCOPES)
+        with jax.named_scope("loss"):
+            return loss_of(params, batch, h, head_h, aux)
+
+    def loss_of(params, batch, h, head_h, aux):
         d = h.shape[-1]
         rows = h.reshape(-1, d)
         w = params["lm_head"]
@@ -323,10 +329,11 @@ def build_train_step(arch: Arch, tc: TrainConfig,
 
     def step_fn(state, batch):
         loss, metrics, grads = compute_grads(state["params"], batch)
-        grads, gnorm = clip_by_global_norm(grads, tc.max_grad_norm)
-        lr = sched(state["step"])
-        new_params, new_opt = opt_update(grads, state["opt"],
-                                         state["params"], lr)
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, tc.max_grad_norm)
+            lr = sched(state["step"])
+            new_params, new_opt = opt_update(grads, state["opt"],
+                                             state["params"], lr)
         new_state = {"params": new_params, "opt": new_opt,
                      "step": state["step"] + 1}
         metrics = dict(metrics, loss=loss, grad_norm=gnorm, lr=lr)

@@ -1,15 +1,20 @@
 """repro.obs: fake-clock span semantics, histogram quantiles vs numpy,
 the disabled no-op identity (same scheduler tokens, zero instruments),
-JSONL / Chrome trace round-trips, and the export formats."""
+JSONL / Chrome trace round-trips, the export formats, the layer scopes
+in a compiled train step, the train loop's spans and the compile
+counters."""
 
 import json
+import re
 
 import numpy as np
 import pytest
 
 import repro.serve.scheduler as sched_mod
 from repro import obs
+from repro.distributed.fault import StragglerMonitor
 from repro.obs.metrics import NULL_METRIC
+from repro.train import train_loop
 from tests.test_scheduler import FakeClock, FakeEngine
 
 
@@ -280,23 +285,6 @@ def test_metrics_report_and_dump_json(tmp_path, capsys):
     assert '"x": 1' in capsys.readouterr().out
 
 
-def test_prometheus_format():
-    reg = obs.Registry()
-    reg.counter("serve.tokens_total", "tokens").inc(7)
-    reg.gauge("kvpool.blocks_in_use").set(3)
-    h = reg.histogram("serve.ttft_s", bounds=[0.1, 1.0])
-    for v in (0.05, 0.5, 2.0):
-        h.observe(v)
-    text = obs.export.to_prometheus(reg)
-    assert "# TYPE repro_serve_tokens_total counter" in text
-    assert "repro_serve_tokens_total 7" in text
-    assert "repro_kvpool_blocks_in_use 3" in text
-    assert 'repro_serve_ttft_s_bucket{le="0.1"} 1' in text
-    assert 'repro_serve_ttft_s_bucket{le="1"} 2' in text
-    assert 'repro_serve_ttft_s_bucket{le="+Inf"} 3' in text
-    assert "repro_serve_ttft_s_count 3" in text
-
-
 def test_write_trace_formats(tmp_path):
     with obs.capture(trace=True) as (_, tracer):
         with tracer.span("s"):
@@ -363,3 +351,115 @@ def test_beam_group_metrics_and_fork_instrumentation():
         assert snap["kvpool.blocks_in_use"]["value"] == \
             eng.pool.used_blocks
         assert len(sched.hypotheses[rid]) == 3
+
+
+# -- layer scopes, loop spans, compile counters ----------------------------
+
+def _scopes_of(op_name):
+    """The `obs.SCOPES` named by an op_name's components, transform
+    wrappers such as ``transpose(jvp(attn))`` unwrapped."""
+    return [s for s in obs.SCOPES
+            if re.search(rf"(^|[/(]){s}([/)]|$)", op_name)]
+
+
+def test_every_matmul_and_call_in_the_train_step_has_a_scope():
+    import jax
+    import jax.numpy as jnp
+    from repro.models.registry import get_arch
+    from repro.train.step import TrainConfig, build_train_step
+    arch = get_arch("qwen3-0.6b", reduced=True)
+    tc = TrainConfig(loss_impl="pallas", loss_block_v=128, total_steps=10,
+                     warmup_steps=1)
+    init_fn, step_fn = build_train_step(arch, tc)
+    state = jax.eval_shape(init_fn, jax.ShapeDtypeStruct((2,), jnp.uint32))
+    batch = {k: jax.ShapeDtypeStruct((2, 16), jnp.int32)
+             for k in ("tokens", "targets")}
+    text = jax.jit(step_fn, donate_argnums=(0,)).lower(
+        state, batch).compile().as_text()
+    ops = re.compile(r"= \S+ (dot|convolution|custom-call)\(")
+    seen, bare = 0, []
+    for line in text.splitlines():
+        if not ops.search(line):
+            continue
+        seen += 1
+        m = re.search(r'op_name="([^"]*)"', line)
+        if m is None or not _scopes_of(m.group(1)):
+            bare.append(line.strip()[:160])
+    assert seen > 10
+    assert not bare, bare
+    # forward, backward and recompute of a layer each keep its scope
+    for pat in (r'jvp\(blocks\)/[^"]*/attn/',
+                r'transpose\(jvp\(blocks\)\)[^"]*/rematted_computation/mlp/',
+                r'/optimizer/'):
+        assert re.search(pat, text), pat
+
+
+class _Stay:
+    """A preemption stand-in that never asks to stop."""
+
+    should_stop = False
+
+    def install(self):
+        return self
+
+
+def _run_loop(steps=2):
+    import jax.numpy as jnp
+
+    def step_fn(state, batch):
+        return {"step": state["step"] + 1}, {"loss": jnp.float32(1.0)}
+
+    data = [{"tokens": np.zeros((2, 4), np.int32)}] * steps
+    return train_loop(state={"step": jnp.int32(0)}, step_fn=step_fn,
+                      data=data, num_steps=steps, log_every=100,
+                      preemption=_Stay(), straggler=StragglerMonitor())
+
+
+def test_train_loop_spans_nest_under_the_step():
+    with obs.capture(trace=True, clock=make_ticker()) as (_, tr):
+        _run_loop(steps=2)
+    got = [(s.name, s.start, s.end, s.depth) for s in tr.spans]
+    # ticker: each clock read advances 1; step 0 is also the logged step
+    assert got[:5] == [("train.feed", 2.0, 3.0, 1),
+                       ("train.dispatch", 4.0, 5.0, 1),
+                       ("train.wait", 6.0, 7.0, 1),
+                       ("train.step", 1.0, 8.0, 0),
+                       ("train.log", 9.0, 10.0, 0)]
+    assert [s.name for s in tr.spans[5:]] == [
+        "train.feed", "train.dispatch", "train.wait", "train.step"]
+    assert tr.spans[-1].args == {"step": 1}
+
+
+def test_train_loop_records_nothing_under_the_null_tracer():
+    obs.disable()
+    assert obs.get_tracer() is obs.NULL_TRACER
+    _run_loop(steps=2)
+    assert obs.NULL_TRACER.spans == ()
+    assert len(obs.get_registry()) == 0
+
+
+def test_a_fresh_jit_counts_one_backend_compile():
+    import jax
+    import jax.numpy as jnp
+    x = jnp.arange(7.0)
+    f = jax.jit(lambda v: v * 3.0 + 1.0)
+    with obs.capture(trace=False) as (reg, _):
+        assert all(reg.get(n).value == 0 for n in obs.COMPILE_COUNTERS)
+        f(x).block_until_ready()
+        assert reg.get("compile.backend_compiles").value == 1
+        assert reg.get("compile.backend_s").value > 0
+        f(x).block_until_ready()
+        assert reg.get("compile.backend_compiles").value == 1
+    # a disabled registry is fed nothing
+    obs.disable()
+    jax.jit(lambda v: v - 2.0)(x).block_until_ready()
+    assert len(obs.get_registry()) == 0
+
+
+def test_enable_twice_registers_the_listeners_once():
+    from jax._src import monitoring as jm
+    obs.enable()
+    obs.enable(trace=True)
+    obs.disable()
+    assert jm._event_listeners.count(obs._on_event) == 1
+    assert jm._event_duration_secs_listeners.count(obs._on_duration) == 1
