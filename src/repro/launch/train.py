@@ -13,7 +13,8 @@ needs ``--devices``.
 
 ``--stats-json [PATH]`` dumps the logged step history as JSON;
 ``--metrics-json [PATH]`` enables `repro.obs` and dumps the step-time,
-loss and ``compile.*`` instruments; ``--trace-out PATH`` records a
+loss, ``compile.*`` and ``attn.*`` (attention call sites by path)
+instruments; ``--trace-out PATH`` records a
 ``train.step`` span per step holding ``train.feed``, ``train.dispatch``
 and ``train.wait`` (bridged to the profiler's annotations so host spans
 line up with device profiles) — see DESIGN.md §11.

@@ -12,6 +12,13 @@ the repo's unifying numeric primitive.
 
 Decode uses the KV cache with a single masked einsum (q_len == 1: scores are
 O(S), no tiling needed).
+
+The training call (`attention_layer` without a cache) takes the Pallas
+flash-attention kernels of `kernels/flash_attn` instead of the jnp loops
+where they apply (`_kernel_block`): on a TPU, causal, no window or
+softcap, unsharded, head_dim and T on the kernels' 128-wide tiling.  The
+serving prefills always keep the jnp recurrence: `extend_attention` is
+bit-identical to it, and that contract holds for the jnp path only.
 """
 
 from __future__ import annotations
@@ -24,6 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+from repro.kernels import flash_attn
 from repro.models import layers as L
 
 _NEG_INF = float("-inf")
@@ -288,28 +297,52 @@ def _flash_bwd_impl(q, k, v, out, lse, dout, cfg: AttnConfig, kv_len: int):
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q, k, v, cfg: AttnConfig, kv_len: int):
-    return _flash_fwd_impl(q, k, v, cfg, kv_len)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, cfg: AttnConfig, kv_len: int, block: Optional[int]):
+    """The jnp recurrence, or with a `block` the flash-attention
+    kernels."""
+    return _flash_fwd(q, k, v, cfg, kv_len, block)[0]
 
 
-def _flash_fwd(q, k, v, cfg, kv_len):
-    out, lse = _flash_fwd_impl(q, k, v, cfg, kv_len)
+def _flash_fwd(q, k, v, cfg, kv_len, block):
+    if block is not None:
+        out, lse = flash_attn.flash_fwd(q, k, v, block)
+    else:
+        out, lse = _flash_fwd_impl(q, k, v, cfg, kv_len)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(cfg, kv_len, res, dout):
+def _flash_bwd(cfg, kv_len, block, res, dout):
     q, k, v, out, lse = res
-    dq, dk, dv = _flash_bwd_impl(q, k, v, out, lse, dout, cfg, kv_len)
+    if block is not None:
+        dq, dk, dv = flash_attn.flash_bwd(q, k, v, out, lse, dout, block)
+    else:
+        dq, dk, dv = _flash_bwd_impl(q, k, v, out, lse, dout, cfg, kv_len)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _kernel_block(q, k, cfg: AttnConfig, shard=None) -> Optional[int]:
+    """The flash-attention kernels' block for a training call, or None
+    where the call keeps the jnp loops: off a TPU, non-causal, a local
+    window, a softcap, a sharded model (GSPMD cannot partition a Pallas
+    call), or shapes off the kernels' tiling."""
+    if (shard is not None or not _on_tpu() or not cfg.causal
+            or cfg.window is not None or cfg.attn_softcap is not None):
+        return None
+    return flash_attn.choose_block(q.shape[1], cfg.head_dim,
+                                   q.shape[2] // k.shape[2])
+
+
 def blockwise_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, cfg: AttnConfig,
-    *, kv_len: Optional[int] = None,
+    *, kv_len: Optional[int] = None, block: Optional[int] = None,
 ) -> jax.Array:
     """Online-softmax (FlashAttention-style) attention with custom VJP.
 
@@ -317,7 +350,17 @@ def blockwise_attention(
     (chunk_q x chunk_k) block at a time, forward AND backward (the backward
     recomputes tiles, exactly like the paper's fused-loss backward).
     kv_len masks padded kv positions (defaults to Tk).
+
+    With a `block` (from `_kernel_block`; causal self-attention whose T
+    the block divides) the three `kernels/flash_attn` kernels run the
+    same recurrence.  Each traced call counts its path in `repro.obs`:
+    ``attn.kernel_sites`` or ``attn.jnp_sites``.
     """
+    kernel_sites, jnp_sites = obs.ATTN_COUNTERS
+    obs.get_registry().counter(
+        jnp_sites if block is None else kernel_sites).inc()
+    if block is not None:
+        return _flash(q, k, v, cfg, k.shape[1], block).astype(q.dtype)
     b, tq, nq, hd = q.shape
     tk = k.shape[1]
     kv_len = tk if kv_len is None else kv_len
@@ -326,7 +369,7 @@ def blockwise_attention(
     q = _pad_axis1(q, pad_q)
     k = _pad_axis1(k, pad_k)
     v = _pad_axis1(v, pad_k)
-    out = _flash(q, k, v, cfg, kv_len)
+    out = _flash(q, k, v, cfg, kv_len, None)
     return out[:, :tq].astype(q.dtype)
 
 
@@ -459,7 +502,8 @@ def attention_layer(
     is_decode = decode or t == 1
     new_cache = None
     if cache is None:
-        out = blockwise_attention(q, k, v, cfg)
+        out = blockwise_attention(q, k, v, cfg,
+                                  block=_kernel_block(q, k, cfg, shard))
     elif "table" in cache:                                # paged block-pool
         quant = "kp_scale" in cache             # int8 pools + scale pools
         table = cache["table"]

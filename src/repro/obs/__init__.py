@@ -26,7 +26,9 @@ inside compiled programs: each layer boundary opens one
 ``jax.named_scope`` of that tuple at its call site, so every device op
 carries its layer in its ``op_name`` metadata.  And `enable` installs,
 once per process, `jax.monitoring` listeners that count compilations
-into whichever registry is current (``compile.*``).
+into whichever registry is current (``compile.*``), and starts the
+``attn.*`` counters at 0: the attention call sites count, as they are
+traced, which path they took (`models/attention.py`).
 """
 
 from __future__ import annotations
@@ -50,6 +52,7 @@ __all__ = [
     "chrome_trace_events", "read_jsonl", "request_coverage",
     "get_registry", "get_tracer", "set_registry", "set_tracer",
     "enable", "disable", "capture", "SCOPES", "COMPILE_COUNTERS",
+    "ATTN_COUNTERS",
     "export", "metrics", "trace",
 ]
 
@@ -78,6 +81,9 @@ _TIMED = {
     "/jax/compilation_cache/cache_retrieval_time_sec": "compile.cache_load_s",
 }
 COMPILE_COUNTERS = tuple(_COUNTED.values()) + tuple(_TIMED.values())
+# attention call sites traced through the flash-attention kernels, and
+# through the jnp loops (`models/attention.py::blockwise_attention`)
+ATTN_COUNTERS = ("attn.kernel_sites", "attn.jnp_sites")
 _listeners_lock = threading.Lock()
 _listeners_installed = False
 
@@ -139,9 +145,9 @@ def enable(trace: bool = False,
     Returns ``(registry, tracer)`` — the tracer is :data:`NULL_TRACER`
     when tracing stays off.  Call BEFORE constructing the engines /
     schedulers / pools you want instrumented.  The ``compile.*``
-    counters start at 0 in the new registry."""
+    and ``attn.*`` counters start at 0 in the new registry."""
     reg = Registry(enabled=True)
-    for name in COMPILE_COUNTERS:
+    for name in COMPILE_COUNTERS + ATTN_COUNTERS:
         reg.counter(name)
     tr = Tracer(clock=clock, jax_annotate=jax_annotate) if trace \
         else NULL_TRACER

@@ -3,7 +3,8 @@
 Nothing runs here.  The TPU compiler installed with jaxlib compiles for a
 chip that is described and not attached (topology ``v5e:2x2``, one of its
 devices), at the published widths of qwen3-0.6b: d=1024, V=151936, a
-training batch of 8 x 1024 rows and a decode batch of 8.  It refuses what
+training batch of 8 x 1024 rows, a decode batch of 8, and the training
+cell's attention core (4 x 4096 tokens, 16/8 heads).  It refuses what
 interpret mode accepts: blocks that break Mosaic's (8, 128) tiling rule
 and kernels that need more scoped VMEM than they ask for.
 
@@ -24,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.types import LossConfig
 from repro.core.windows import choose_blocks
+from repro.kernels import flash_attn
 from repro.kernels.fused_ce import kernel as fused_ce
 from repro.kernels.paged_attn.kernel import pallas_paged_attention
 from repro.kernels.pallas_utils import tpu_kernels
@@ -35,6 +37,7 @@ N_TRAIN = 8 * 1024                  # global batch 8 x sequence 1024
 B_DECODE = 8
 NQ, NKV, HD = 16, 8, 128            # qwen3-0.6b attention heads
 BLOCK, MAX_LEN = 16, 256            # paged KV: tokens per block, per slot
+B_ATTN, T_ATTN = 4, 4096            # the training cell's attention batch
 
 
 @pytest.fixture(scope="module")
@@ -159,3 +162,21 @@ def test_paged_attn_compiles(one_chip, quantized):
         return pallas_paged_attention(q, kp, vp, table, lens, kp_scale=ks,
                                       vp_scale=vs, interpret=False)
     _compile(fn, one_chip, shapes, ["paged_attn"])
+
+
+@pytest.mark.parametrize("phase", ["fwd", "bwd"])
+def test_flash_attn_compiles(one_chip, phase):
+    """The training cell's attention core: 4 x 4096 tokens, 16 query and
+    8 KV heads of 128, f32 activations, the block the shapes choose."""
+    block = flash_attn.choose_block(T_ATTN, HD, NQ // NKV)
+    q = ((B_ATTN, T_ATTN, NQ, HD), jnp.float32)
+    kv = ((B_ATTN, T_ATTN, NKV, HD), jnp.float32)
+    if phase == "fwd":
+        _compile(lambda q, k, v: flash_attn.flash_fwd(
+            q, k, v, block, interpret=False), one_chip, (q, kv, kv),
+            ["flash_attn_fwd"])
+    else:
+        stats = ((B_ATTN, NKV, NQ // NKV, T_ATTN), jnp.float32)
+        _compile(lambda q, k, v, o, lse, do: flash_attn.flash_bwd(
+            q, k, v, o, lse, do, block, interpret=False), one_chip,
+            (q, kv, kv, q, stats, q), ["flash_attn_dkv", "flash_attn_dq"])
