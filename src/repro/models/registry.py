@@ -15,6 +15,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import Arch
 
 # Families whose trunks take the registry-level MTP heads (DESIGN.md §7).
@@ -86,6 +87,38 @@ def apply_mtp_heads(arch: Arch, params, h: jax.Array) -> jax.Array:
                        eps=getattr(arch.cfg, "norm_eps", 1e-6))
 
 
+def cast_params(arch: Arch, params):
+    """The master weights as the forward computes with them (DESIGN.md
+    §14): every floating leaf in the config's `compute_dtype`, the
+    gradient flowing back through the cast into the stored dtype.
+    Callers open the `cast` scope of `repro.obs.SCOPES` around it.  The
+    embedding table is left as stored: the forward casts the rows it
+    looks up, so the table's gradient accumulates in the stored dtype
+    too.  With `param_dtype` equal to `compute_dtype` this returns
+    `params` itself, and a leaf already in `compute_dtype` passes
+    unchanged.  Counts, as it is traced, the leaves it casts
+    (``precision.cast_leaves``) and their stored bytes
+    (``precision.cast_bytes``)."""
+    cdt = jnp.dtype(getattr(arch.cfg, "compute_dtype", "float32"))
+    if jnp.dtype(getattr(arch.cfg, "param_dtype", "float32")) == cdt:
+        return params
+    cast = []
+
+    def one(path, x):
+        if (path[0].key == "embed" or x.dtype == cdt
+                or not jnp.issubdtype(x.dtype, jnp.floating)):
+            return x
+        cast.append(x.size * x.dtype.itemsize)
+        return x.astype(cdt)
+
+    out = jax.tree_util.tree_map_with_path(one, params)
+    leaves, nbytes = obs.PRECISION_COUNTERS
+    reg = obs.get_registry()
+    reg.counter(leaves).inc(len(cast))
+    reg.counter(nbytes).inc(sum(cast))
+    return out
+
+
 def forward_hidden(
     arch: Arch, params, batch: Dict[str, Any], *,
     caches=None, shard=None, decode: bool = False,
@@ -113,6 +146,8 @@ def forward_hidden(
     forward (DESIGN.md §7.1).
     """
     mod = _family_mod(arch)
+    with jax.named_scope("cast"):
+        params = cast_params(arch, params)
     kwargs = dict(shard=shard, decode=decode)
     fe = batch.get("frontend_embeds")
     if arch.family == "transformer":

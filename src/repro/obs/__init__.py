@@ -28,7 +28,9 @@ carries its layer in its ``op_name`` metadata.  And `enable` installs,
 once per process, `jax.monitoring` listeners that count compilations
 into whichever registry is current (``compile.*``), and starts the
 ``attn.*`` counters at 0: the attention call sites count, as they are
-traced, which path they took (`models/attention.py`).
+traced, which path they took (`models/attention.py`), and the
+``precision.*`` counters at 0: the master weights cast to the compute
+dtype, as the casts are traced (`models/registry.py::cast_params`).
 """
 
 from __future__ import annotations
@@ -52,7 +54,7 @@ __all__ = [
     "chrome_trace_events", "read_jsonl", "request_coverage",
     "get_registry", "get_tracer", "set_registry", "set_tracer",
     "enable", "disable", "capture", "SCOPES", "COMPILE_COUNTERS",
-    "ATTN_COUNTERS",
+    "ATTN_COUNTERS", "PRECISION_COUNTERS",
     "export", "metrics", "trace",
 ]
 
@@ -65,7 +67,7 @@ _tracer = NULL_TRACER
 # layer boundary; an op's layer is the innermost of these in its
 # `op_name`.  JAX adds `rematted_computation` to ops that a checkpoint
 # recomputes in the backward pass.
-SCOPES = ("embed", "blocks", "norm", "attn", "mlp", "moe", "loss",
+SCOPES = ("cast", "embed", "blocks", "norm", "attn", "mlp", "moe", "loss",
           "optimizer")
 
 # jax.monitoring events -> the compile.* counters they feed.  The backend
@@ -84,6 +86,9 @@ COMPILE_COUNTERS = tuple(_COUNTED.values()) + tuple(_TIMED.values())
 # attention call sites traced through the flash-attention kernels, and
 # through the jnp loops (`models/attention.py::blockwise_attention`)
 ATTN_COUNTERS = ("attn.kernel_sites", "attn.jnp_sites")
+# master-weight leaves cast to the compute dtype, and their stored bytes,
+# as the casts are traced (`models/registry.py::cast_params`)
+PRECISION_COUNTERS = ("precision.cast_leaves", "precision.cast_bytes")
 _listeners_lock = threading.Lock()
 _listeners_installed = False
 
@@ -144,10 +149,11 @@ def enable(trace: bool = False,
 
     Returns ``(registry, tracer)`` — the tracer is :data:`NULL_TRACER`
     when tracing stays off.  Call BEFORE constructing the engines /
-    schedulers / pools you want instrumented.  The ``compile.*``
-    and ``attn.*`` counters start at 0 in the new registry."""
+    schedulers / pools you want instrumented.  The ``compile.*``,
+    ``attn.*`` and ``precision.*`` counters start at 0 in the new
+    registry."""
     reg = Registry(enabled=True)
-    for name in COMPILE_COUNTERS + ATTN_COUNTERS:
+    for name in COMPILE_COUNTERS + ATTN_COUNTERS + PRECISION_COUNTERS:
         reg.counter(name)
     tr = Tracer(clock=clock, jax_annotate=jax_annotate) if trace \
         else NULL_TRACER

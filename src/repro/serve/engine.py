@@ -36,10 +36,11 @@ import numpy as np
 
 from repro import obs
 from repro.configs.base import Arch, ENCDEC_SERVE_ENC_LEN
-from repro.models.registry import (cache_batch_axes, empty_serve_caches,
-                                   forward_hidden, init_serve_caches,
-                                   insert_slot_caches, reset_slot_caches,
-                                   shift_cache_lens, take_slot_caches)
+from repro.models.registry import (cache_batch_axes, cast_params,
+                                   empty_serve_caches, forward_hidden,
+                                   init_serve_caches, insert_slot_caches,
+                                   reset_slot_caches, shift_cache_lens,
+                                   take_slot_caches)
 from repro.serve.sampler import sample_tokens
 
 
@@ -181,7 +182,9 @@ class Engine:
     def __init__(self, arch: Arch, params, sc: ServeConfig,
                  jit: bool = True):
         self.arch = arch
-        self.params = params
+        # the weights in the compute dtype once, not at every step
+        # (DESIGN.md §14)
+        self.params = params = cast_params(arch, params)
         self.sc = sc
         self._jit = jit
         self._cdt = jnp.dtype(sc.cache_dtype)

@@ -32,7 +32,7 @@ from repro.core import fused_cross_entropy, LossConfig
 from repro.core.fused_ce import default_impl
 from repro.core.windows import BlockPlan
 from repro.core.sharded import make_sharded_loss
-from repro.models.registry import forward_hidden
+from repro.models.registry import cast_params, forward_hidden
 from repro.optim import make_optimizer, clip_by_global_norm
 from repro.optim import schedules as S
 from repro.sharding.rules import AxisRules
@@ -162,6 +162,10 @@ def build_loss_fn(arch: Arch, tc: TrainConfig,
         return sharded_cache[key]
 
     def loss_fn(params, batch):
+        # one cast of the master weights, the lm_head's included, for the
+        # forward and the loss alike (DESIGN.md §14)
+        with jax.named_scope("cast"):
+            params = cast_params(arch, params)
         if n_mtp:
             h, head_h, aux, _ = forward_hidden(arch, params, batch,
                                                shard=shard,

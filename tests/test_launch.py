@@ -34,6 +34,35 @@ def test_train_cli_end_to_end(tmp_path):
     assert int(jax.device_get(state2["step"])) == 8
 
 
+def test_train_cli_reports_the_weight_cast(tmp_path, monkeypatch):
+    """f32 masters under bf16 compute (the full qwen3-0.6b config's
+    precision) train through the CLI, and `--metrics-json` reports the
+    leaves cast and their bytes."""
+    import dataclasses
+    import json
+    from repro.launch import train
+    full = get_arch("qwen3-0.6b")
+    assert (full.cfg.param_dtype, full.cfg.compute_dtype) == (
+        "float32", "bfloat16")
+
+    def tiny_mixed(arch_id, reduced=False):
+        arch = get_arch(arch_id, reduced=reduced)
+        return dataclasses.replace(arch, cfg=dataclasses.replace(
+            arch.cfg, param_dtype=full.cfg.param_dtype,
+            compute_dtype=full.cfg.compute_dtype))
+    monkeypatch.setattr(train, "get_arch", tiny_mixed)
+    out = tmp_path / "metrics.json"
+    state, history = train.main([
+        "--arch", "qwen3-0.6b", "--reduced", "--steps", "2",
+        "--global-batch", "2", "--seq-len", "16", "--log-every", "1",
+        "--metrics-json", str(out)])
+    assert np.isfinite(history[-1][1]["loss"])
+    assert state["params"]["lm_head"].dtype == jnp.float32
+    got = json.loads(out.read_text())["metrics"]
+    assert got["precision.cast_leaves"]["value"] > 0
+    assert got["precision.cast_bytes"]["value"] > 0
+
+
 def test_train_cli_sharded_loss_needs_a_mesh():
     """--loss-impl sharded without --devices used to train with the
     streaming loss instead; it must refuse."""

@@ -394,6 +394,42 @@ def test_every_matmul_and_call_in_the_train_step_has_a_scope():
         assert re.search(pat, text), pat
 
 
+@pytest.mark.parametrize("compute", ["bfloat16", "float32"])
+def test_the_weight_cast_has_its_scope_and_counters(compute):
+    """Under bf16 compute the f32 masters are cast once, under the `cast`
+    scope, and the counters read the leaves cast (every leaf but the
+    embedding table) and their f32 bytes; with compute in f32 no op
+    carries the scope and the counters read 0."""
+    import dataclasses
+    import jax
+    import jax.numpy as jnp
+    from repro.models.registry import get_arch
+    from repro.train.step import TrainConfig, build_train_step
+    arch = get_arch("qwen3-0.6b", reduced=True)
+    arch = dataclasses.replace(arch, cfg=dataclasses.replace(
+        arch.cfg, param_dtype="float32", compute_dtype=compute))
+    tc = TrainConfig(loss_impl="streaming", loss_block_v=128,
+                     total_steps=10, warmup_steps=1)
+    init_fn, step_fn = build_train_step(arch, tc)
+    state = jax.eval_shape(init_fn, jax.ShapeDtypeStruct((2,), jnp.uint32))
+    batch = {k: jax.ShapeDtypeStruct((2, 16), jnp.int32)
+             for k in ("tokens", "targets")}
+    with obs.capture() as (reg, _):
+        text = jax.jit(step_fn).lower(state, batch).as_text(
+            debug_info=True)
+        snap = reg.snapshot()
+    cast = [x for k, x in jax.tree_util.tree_flatten_with_path(
+        state["params"])[0] if k[0].key != "embed"]
+    mixed = compute == "bfloat16"
+    assert snap["precision.cast_leaves"]["value"] == (
+        len(cast) if mixed else 0)
+    assert snap["precision.cast_bytes"]["value"] == (
+        sum(4 * x.size for x in cast) if mixed else 0)
+    assert bool(re.search(r'jvp\(cast\)/convert_element_type', text)) \
+        == mixed
+    assert bool(re.search(r'transpose\(jvp\(cast\)\)', text)) == mixed
+
+
 class _Stay:
     """A preemption stand-in that never asks to stop."""
 
