@@ -10,7 +10,7 @@ random weights from a seed, through the entry points a user calls:
 
   train   `repro.launch.train` takes 5 steps of global batch 8 x 1024
           tokens with the Pallas fused loss.  Every loss is finite, and
-          the compiled step calls the forward, dH and dW kernels.
+          the compiled step calls the forward and backward kernels.
   loss    The Pallas fused CE against the f32 two-stage `canonical` loss
           on the same (8192, 1024) hidden states and the trained lm_head,
           twice: in bf16 as the train step runs it (its plan, and the
@@ -177,7 +177,7 @@ def phase_train():
         f"aliased {mem.alias_size_in_bytes} B")
     say(f"[train] tpu_custom_call {len(kernels)}: {kernels}")
     check(len(kernels) >= 3 and _has_kernels(
-        kernels, ("fused_ce_fwd", "fused_ce_dh", "fused_ce_dw")),
+        kernels, ("fused_ce_fwd", "fused_ce_bwd")),
         f"train step kernels {kernels}")
     return res.state["params"]["lm_head"]
 
@@ -256,8 +256,7 @@ def phase_loss(w):
     for dtype in (jnp.bfloat16, jnp.float32):
         args = (h.astype(dtype), w.astype(dtype))
         kernels = _kernels_of(run, *args)
-        check(_has_kernels(kernels, ("fused_ce_fwd", "fused_ce_dh",
-                                     "fused_ce_dw")),
+        check(_has_kernels(kernels, ("fused_ce_fwd", "fused_ce_bwd")),
               f"pallas loss kernels {kernels}")
         loss, dh, dw = run(*args)
         loss = float(loss)
@@ -361,8 +360,7 @@ def phase_four_chips():
         f"median {median:.3f} s, phase {wall:.1f} s")
     kernels = _kernels_of(res.step, res.state, _batch_spec())
     say(f"[4chip] tpu_custom_call {len(kernels)}: {kernels}")
-    check(_has_kernels(kernels, ("fused_ce_fwd", "fused_ce_dh",
-                                 "fused_ce_dw")),
+    check(_has_kernels(kernels, ("fused_ce_fwd", "fused_ce_bwd")),
           f"sharded train step kernels {kernels}")
 
     arch = get_arch(ARCH)
@@ -418,8 +416,7 @@ def phase_four_chips():
              jax.device_put(w, NamedSharding(mesh, P("model", None))),
              jax.device_put(y, NamedSharding(mesh, P("data"))))
     kernels = _kernels_of(f4, *args4)
-    check(_has_kernels(kernels, ("fused_ce_fwd", "fused_ce_dh",
-                                 "fused_ce_dw")),
+    check(_has_kernels(kernels, ("fused_ce_fwd", "fused_ce_bwd")),
           f"sharded loss kernels {kernels}")
     l4, (dh4, dw4) = f4(*args4)
     l1, (dh1, dw1) = jax.jit(jax.value_and_grad(

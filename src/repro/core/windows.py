@@ -62,19 +62,22 @@ def tile_bytes(bm: int, bv: int, d: int, in_bytes: int = 2) -> int:
     return 2 * (h_tile + w_tile) + logits + state
 
 
-def bwd_tile_bytes(bm: int, bv: int, d: int, in_bytes: int = 2) -> int:
-    """Backward VMEM bytes of one grid step — the dW kernel's, the larger
-    of the two: double-buffered H/W tiles and f32 (bv, d) output block,
-    four (bm, 1) f32 row vectors (lane-padded to 128), and the
-    temporaries of the step (the f32 logit, probability and gradient
-    tiles, the gradient cast to the input dtype, an f32 (bm, d)
-    relayout)."""
+def bwd_tile_bytes(bm: int, bv: int, d: int, in_bytes: int = 2,
+                   rmw_dw: bool = True) -> int:
+    """VMEM bytes of one grid step of the backward kernel: double-buffered
+    H/W tiles and four (bm, 1) f32 row vectors (lane-padded to 128); the
+    resident f32 gradient block, double-buffered as an output; the three
+    f32 slots of the read-modify-written block (dW's (bv, d) when
+    `rmw_dw`, else dH's (bm, d)); and the step's temporaries: the f32
+    logit, probability and gradient tiles, the gradient cast to the input
+    dtype, and the f32 (bm, d) and (bv, d) products."""
     h_tile = bm * d * in_bytes
     w_tile = bv * d * in_bytes
-    out = bv * d * 4
     rows = 4 * bm * _LANE * 4
-    temps = 4 * bm * bv * 4 + bm * d * 4
-    return 2 * (h_tile + w_tile + out + rows) + temps
+    dh, dw = bm * d * 4, bv * d * 4
+    resident, rmw = (dh, dw) if rmw_dw else (dw, dh)
+    temps = 4 * bm * bv * 4 + (bm + bv) * d * 4
+    return 2 * (h_tile + w_tile + rows + resident) + 3 * rmw + temps
 
 
 def scoped_vmem_limit(working_set_bytes: int) -> int:

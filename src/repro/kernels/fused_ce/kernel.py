@@ -10,25 +10,35 @@ TPU adaptation of the paper's CUDA design (DESIGN.md §2):
   * the MXU computes the tile GEMM while the VPU performs the
     max/exp/accumulate updates — the TPU analogue of the paper's
     CUDA-core/Tensor-core overlap;
-  * backward is TWO passes (no TPU atomics): a dH kernel accumulating over
-    vocab tiles for fixed row tiles, and a dW kernel accumulating over row
-    tiles for fixed vocab tiles.  Both recompute the logit tile (paper
-    Alg. 2 "logit recompute").
+  * backward is ONE kernel with one logit recompute per tile (paper
+    Alg. 2 "logit recompute"): the tile's gradient `g` feeds both
+    dH_r += g @ W_v and dW_v += g^T @ H_r.
 
 Grid layouts (R = n_rows/bm, Vb = V_padded/bv):
 
-  forward : grid=(R, Vb)  — vocab innermost, state scratch per row tile
-  dH      : grid=(R, Vb)  — vocab innermost, dH output block per row tile
-  dW      : grid=(Vb, R)  — rows  innermost, dW output block per vocab tile
+  forward  : grid=(R, Vb) — vocab innermost, state scratch per row tile
+  backward : grid=(R, Vb) — dH resident per row tile, dW read-modify-written
+             grid=(Vb, R) — dW resident per vocab tile, dH read-modify-written
+
+The backward's orientation comes from the plan: the smaller of the two
+gradient blocks, dW's (bv, d) or dH's (bm, d), is the one kept in HBM
+and read, added to and written back at each step (by DMAs the kernel
+issues, three VMEM slots deep, so the traffic overlaps the products),
+unless that gradient is a single block against several of the other's.
+The paper's GPU backward adds dW with atomics; a TPU has none and needs
+none here: the v5e runs the grid in order on its one TensorCore, both
+axes are declared sequential ("arbitrary"), and a block is read again
+only after the kernel has waited for the DMA of its last write.  Either
+orientation sums in the same order: dH over vocab tiles 0..Vb-1, dW
+over row tiles 0..R-1, in f32.
 
 Gradient filtering (DESIGN.md §9): `fwd_stats(..., return_tile_stats=
 True)` additionally emits a per-(row-block, vocab-block) max-valid-logit
 statistic from the same online scan; `bwd_grads(..., tile_stats=...)`
 with `cfg.grad_filter_eps > 0` derives a sound skip mask from it
-(`core/filtering.py`) and runs the kernels' filtered variants, which
-gate each tile's recompute + MXU accumulate on the mask, scalar-
-prefetched into SMEM.  Without a mask the exact kernels run, bit-for-bit
-the pre-filter code.
+(`core/filtering.py`) and runs the kernel's filtered variant, which
+gates each tile's recompute and both products on the mask, scalar-
+prefetched into SMEM.  Without a mask the exact kernel runs.
 
 Layouts follow Mosaic's tiling rule (a block's last two dims divide by
 (8, 128) or span the array): the tile statistic of one row block is a
@@ -46,6 +56,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.core.types import LossConfig
 from repro.core.windows import (BlockPlan, bwd_tile_bytes,
                                 choose_blocks, tile_bytes)
@@ -258,7 +269,7 @@ def fwd_stats(
 
 
 # ---------------------------------------------------------------------------
-# Backward kernels (two-pass; logit recompute per tile)
+# Backward kernel (one pass; one logit recompute per tile)
 # ---------------------------------------------------------------------------
 
 
@@ -285,61 +296,92 @@ def _grad_tile(h_tile, w_tile, y_tile, lse_tile, gamma_tile, pc_tile,
     return jnp.where(col_valid, g, 0.0)
 
 
-def _dh_kernel(*refs, cfg: LossConfig, valid: int, v_orig: int, bv: int,
-               num_v: int, filtered: bool):
-    """dH for one row tile, accumulated over the sequential vocab axis
-    straight into the resident f32 output block.  `filtered` prepends the
-    scalar-prefetched skip mask (flat (num_r * num_v,) int32 in SMEM):
-    the tile recompute + MXU accumulate never run for masked tiles
-    (DESIGN.md §9); init stays unconditional."""
+def _bwd_kernel(*refs, cfg: LossConfig, valid: int, v_orig: int, bv: int,
+                num_r: int, num_v: int, filtered: bool, rmw_dw: bool):
+    """dH and dW from one recompute of each (bm, bv) logit tile.
+
+    The grid is (outer, inner): (R, Vb) when `rmw_dw`, else (Vb, R).  The
+    gradient the outer axis indexes accumulates in its resident f32
+    output block across the inner sweep.  The other one lives in HBM
+    (`memory_space=ANY`): each step reads its block into one of three
+    f32 VMEM slots, adds to it and writes it back, by DMAs issued here.
+    Step t prefetches step t+1's block, and first waits for the write of
+    step t-2 (t-1 when the inner axis has two blocks, whose next block is
+    the one step t-1 wrote), so that a slot is reused, and a block read
+    again, only after its write has landed.  The first outer sweep starts
+    each block from zero instead of reading it.  The grid runs in order on
+    one core, so no step races another and no atomics are needed.
+
+    `filtered` prepends the scalar-prefetched skip mask (flat
+    (num_r * num_v,) int32 in SMEM): the tile recompute and both products
+    never run for masked tiles (DESIGN.md §9); the inits and the block's
+    DMAs stay unconditional."""
     if filtered:
         skip_ref, *refs = refs
-    off_ref, y_ref, lse_ref, gm_ref, pc_ref, h_ref, w_ref, dh_ref = refs
-    r, v = pl.program_id(0), pl.program_id(1)
+    (off_ref, y_ref, lse_ref, gm_ref, pc_ref, h_ref, w_ref, dh_ref, dw_ref,
+     slots, read_sem, write_sem) = refs
+    o, i = pl.program_id(0), pl.program_id(1)
+    r, v = (o, i) if rmw_dw else (i, o)
+    num_in = num_v if rmw_dw else num_r
+    last = num_r * num_v - 1
+    t = o * num_in + i                          # the step's place in order
+    resident, hbm = (dh_ref, dw_ref) if rmw_dw else (dw_ref, dh_ref)
+    blk = slots.shape[1]
+    lag = 1 if num_in <= 2 else 2
 
-    @pl.when(v == 0)
-    def _init():
-        dh_ref[...] = jnp.zeros_like(dh_ref[...])
+    def dma(step, read):
+        """Step `step`'s block of `hbm` to (read) or from its slot."""
+        slot = step % 3
+        block = hbm.at[pl.ds((step % num_in) * blk, blk)]
+        if read:
+            return pltpu.make_async_copy(block, slots.at[slot],
+                                         read_sem.at[slot])
+        return pltpu.make_async_copy(slots.at[slot], block,
+                                     write_sem.at[slot])
+
+    @pl.when(t >= lag)
+    def _written():
+        dma(t - lag, read=False).wait()
+
+    @pl.when((t + 1 >= num_in) & (t < last))
+    def _prefetch():
+        dma(t + 1, read=True).start()
+
+    @pl.when(i == 0)
+    def _init_resident():
+        resident[...] = jnp.zeros_like(resident[...])
+
+    slot = t % 3
+
+    @pl.when(o == 0)
+    def _init_block():
+        slots[slot] = jnp.zeros(slots.shape[1:], jnp.float32)
+
+    @pl.when(o > 0)
+    def _fetched():
+        dma(t, read=True).wait()
 
     def _accumulate():
         g = _grad_tile(h_ref[...], w_ref[...], y_ref[...], lse_ref[...],
                        gm_ref[...], pc_ref[...], v * bv, off_ref[0, 0],
                        cfg, valid, v_orig)
-        # dH_tile += g @ W_tile      (bm,bv)x(bv,d) on the MXU
-        dh_ref[...] += _grad_dot(g, w_ref[...], ((1,), (0,)))
+        # dH_r += g @ W_v (bm,bv)x(bv,d); dW_v += g^T @ H_r (bv,bm)x(bm,d)
+        dh = _grad_dot(g, w_ref[...], ((1,), (0,)))
+        dw = _grad_dot(g, h_ref[...], ((0,), (0,)))
+        add, put = (dh, dw) if rmw_dw else (dw, dh)
+        resident[...] += add
+        slots[slot] += put
 
     if filtered:
         pl.when(skip_ref[r * num_v + v] == 0)(_accumulate)
     else:
         _accumulate()
+    dma(t, read=False).start()
 
-
-def _dw_kernel(*refs, cfg: LossConfig, valid: int, v_orig: int, bv: int,
-               num_v: int, filtered: bool):
-    """dW for one vocab tile, accumulated over the sequential row axis
-    into the resident f32 output block; `filtered` as in `_dh_kernel`
-    (the same (num_r, num_v) mask, read transposed: this grid is
-    (v, r)-major)."""
-    if filtered:
-        skip_ref, *refs = refs
-    off_ref, y_ref, lse_ref, gm_ref, pc_ref, h_ref, w_ref, dw_ref = refs
-    v, r = pl.program_id(0), pl.program_id(1)
-
-    @pl.when(r == 0)
-    def _init():
-        dw_ref[...] = jnp.zeros_like(dw_ref[...])
-
-    def _accumulate():
-        g = _grad_tile(h_ref[...], w_ref[...], y_ref[...], lse_ref[...],
-                       gm_ref[...], pc_ref[...], v * bv, off_ref[0, 0],
-                       cfg, valid, v_orig)
-        # dW_tile += g^T @ H_tile    (bv,bm)x(bm,d) on the MXU
-        dw_ref[...] += _grad_dot(g, h_ref[...], ((0,), (0,)))
-
-    if filtered:
-        pl.when(skip_ref[r * num_v + v] == 0)(_accumulate)
-    else:
-        _accumulate()
+    @pl.when(t == last)
+    def _drain():
+        for step in range(max(last - lag + 1, 0), last + 1):
+            dma(step, read=False).wait()
 
 
 def bwd_grads(
@@ -351,16 +393,20 @@ def bwd_grads(
     tile_stats: Optional[jax.Array] = None,
     skip_mask: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """(dH, dW) via the two backward Pallas kernels (f32 outputs).
+    """(dH, dW) via the backward Pallas kernel (f32 outputs).
+
+    The plan orients the kernel: the smaller of the (bm, d) dH and
+    (bv, d) dW blocks is the one read, added to and written back at each
+    step, unless it is one block alone against several of the other's.
 
     Gradient filtering (DESIGN.md §9): pass `tile_stats` — the fourth
     output of `fwd_stats(..., return_tile_stats=True)` under the SAME
     plan — and, with `cfg.grad_filter_eps > 0`, vocab tiles whose
-    softmax-mass bound falls below the threshold are skipped in both
-    kernels.  `skip_mask` overrides the derived (num_r, num_v) boolean
-    mask directly (tests force all-False to prove the filtered kernels
-    are bit-identical to the exact ones).  With neither, this is the
-    exact backward, bit-for-bit the code that predates the filter.
+    softmax-mass bound falls below the threshold are skipped.
+    `skip_mask` overrides the derived (num_r, num_v) boolean mask
+    directly (tests force all-False to prove the filtered kernel is
+    bit-identical to the exact one).  With neither, this is the exact
+    backward.
     """
     if w.dtype.itemsize == 1:
         raise NotImplementedError(
@@ -405,35 +451,42 @@ def bwd_grads(
             raise ValueError(
                 f"skip mask shape {skip_mask.shape} does not match the "
                 f"backward grid {(num_r, num_v)} of plan {plan.shape}")
-        # scalar-prefetched into SMEM: the kernels branch on it per tile
+        # scalar-prefetched into SMEM: the kernel branches on it per tile
         prefetch = (skip_mask.astype(jnp.int32).reshape(-1),)
-    kw = dict(cfg=cfg, valid=valid, v_orig=v_orig, bv=bv, num_v=num_v,
-              filtered=filtered)
-    args = prefetch + (off, y2, lse2, gm2, pc2, h, w)
-    vmem = bwd_tile_bytes(bm, bv, d, in_bytes=h.dtype.itemsize)
-
-    def call(kernel, name, grid, row_axis, out_rows, out_block_rows):
-        # index maps see the grid indices, then the prefetched mask ref;
-        # the output tile follows the outer (parallel) grid axis
-        rows = lambda *i: (i[row_axis], 0)
-        cols = lambda *i: (i[1 - row_axis], 0)
-        in_specs = ([pl.BlockSpec((1, 1), lambda *i: (0, 0))]   # col offset
-                    + [pl.BlockSpec((bm, 1), rows)] * 4  # y lse gamma p_coeff
-                    + [pl.BlockSpec((bm, d), rows),             # h
-                       pl.BlockSpec((bv, d), cols)])            # w
-        return pl.pallas_call(
-            functools.partial(kernel, **kw),
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=len(prefetch), grid=grid,
-                in_specs=in_specs,
-                out_specs=pl.BlockSpec((out_block_rows, d),
-                                       lambda *i: (i[0], 0))),
-            out_shape=jax.ShapeDtypeStruct((out_rows, d), jnp.float32),
-            compiler_params=compiler_params(vmem),
-            interpret=interpret,
-            name=name,
-        )(*args)
-
-    dh = call(_dh_kernel, "fused_ce_dh", (num_r, num_v), 0, np_, bm)
-    dw = call(_dw_kernel, "fused_ce_dw", (num_v, num_r), 1, vp, bv)
+    # the smaller block is read-modified-written; a gradient of one block
+    # stays resident while the other has several (DESIGN.md §2.3)
+    rmw_dw = num_r == 1 or (num_v > 1 and bv <= bm)
+    row_axis = 0 if rmw_dw else 1
+    rows = lambda *g: (g[row_axis], 0)          # grid indices, then the mask
+    cols = lambda *g: (g[1 - row_axis], 0)
+    in_specs = ([pl.BlockSpec((1, 1), lambda *g: (0, 0))]      # col offset
+                + [pl.BlockSpec((bm, 1), rows)] * 4     # y lse gamma p_coeff
+                + [pl.BlockSpec((bm, d), rows),                 # h
+                   pl.BlockSpec((bv, d), cols)])                # w
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out_specs = ([pl.BlockSpec((bm, d), rows), hbm] if rmw_dw
+                 else [hbm, pl.BlockSpec((bv, d), cols)])
+    kern = functools.partial(_bwd_kernel, cfg=cfg, valid=valid,
+                             v_orig=v_orig, bv=bv, num_r=num_r, num_v=num_v,
+                             filtered=filtered, rmw_dw=rmw_dw)
+    vmem = bwd_tile_bytes(bm, bv, d, in_bytes=h.dtype.itemsize,
+                          rmw_dw=rmw_dw)
+    bwd_sites, = obs.LOSS_COUNTERS
+    obs.get_registry().counter(bwd_sites).inc()
+    dh, dw = pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(num_r, num_v) if rmw_dw else (num_v, num_r),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((3, bv if rmw_dw else bm, d), jnp.float32),
+                pltpu.SemaphoreType.DMA((3,)),
+                pltpu.SemaphoreType.DMA((3,))]),
+        out_shape=[jax.ShapeDtypeStruct((np_, d), jnp.float32),
+                   jax.ShapeDtypeStruct((vp, d), jnp.float32)],
+        compiler_params=compiler_params(vmem, ("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="fused_ce_bwd",
+    )(*prefetch, off, y2, lse2, gm2, pc2, h, w)
     return dh[:n], dw[:v_orig]

@@ -43,7 +43,7 @@ def ref_stats(h, w, y, cfg: LossConfig) -> Tuple[jax.Array, jax.Array, jax.Array
 
 
 def ref_grads(h, w, y, lse, gamma, p_coeff, cfg: LossConfig):
-    """(dH, dW) — oracle for the backward kernels.
+    """(dH, dW) — oracle for the backward kernel.
 
     gamma:   per-row upstream scale Γ_n           (0 for ignored rows)
     p_coeff: per-row coefficient of the softmax    Γ_n (1 + 2 λ_z lse_n)

@@ -5,7 +5,7 @@ interpret-mode backend check, and the kernels a compiled program holds."""
 from __future__ import annotations
 
 import re
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
 import jax
 from jax.experimental.pallas import tpu as pltpu
@@ -13,10 +13,13 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.windows import scoped_vmem_limit
 
 
-def compiler_params(working_set_bytes: Optional[int] = None):
-    """dimension_semantics: first grid axis parallel, second sequential —
-    the layout every kernel in this repo uses (state is carried across
-    the innermost, sequential axis).
+def compiler_params(working_set_bytes: Optional[int] = None,
+                    semantics: Tuple[str, ...] = ("parallel", "arbitrary")):
+    """dimension_semantics: by default the first grid axis parallel, the
+    second sequential — the layout the kernels of this repo use (state is
+    carried across the innermost, sequential axis).  A kernel that carries
+    state across both axes (the fused loss's backward) passes
+    ("arbitrary", "arbitrary").
 
     `working_set_bytes` (the kernel's VMEM model for one grid step, from
     `core/windows.py`) sets Mosaic's scoped-VMEM limit to cover it;
@@ -24,8 +27,7 @@ def compiler_params(working_set_bytes: Optional[int] = None):
     kw = {}
     if working_set_bytes is not None:
         kw["vmem_limit_bytes"] = scoped_vmem_limit(working_set_bytes)
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"), **kw)
+    return pltpu.CompilerParams(dimension_semantics=semantics, **kw)
 
 
 def interpret_default() -> bool:

@@ -30,7 +30,9 @@ into whichever registry is current (``compile.*``), and starts the
 ``attn.*`` counters at 0: the attention call sites count, as they are
 traced, which path they took (`models/attention.py`), and the
 ``precision.*`` counters at 0: the master weights cast to the compute
-dtype, as the casts are traced (`models/registry.py::cast_params`).
+dtype, as the casts are traced (`models/registry.py::cast_params`), and
+``fused_ce.bwd_sites`` at 0: the fused loss's backward kernels, as they
+are traced (`kernels/fused_ce/kernel.py::bwd_grads`).
 """
 
 from __future__ import annotations
@@ -54,7 +56,7 @@ __all__ = [
     "chrome_trace_events", "read_jsonl", "request_coverage",
     "get_registry", "get_tracer", "set_registry", "set_tracer",
     "enable", "disable", "capture", "SCOPES", "COMPILE_COUNTERS",
-    "ATTN_COUNTERS", "PRECISION_COUNTERS",
+    "ATTN_COUNTERS", "PRECISION_COUNTERS", "LOSS_COUNTERS",
     "export", "metrics", "trace",
 ]
 
@@ -89,6 +91,9 @@ ATTN_COUNTERS = ("attn.kernel_sites", "attn.jnp_sites")
 # master-weight leaves cast to the compute dtype, and their stored bytes,
 # as the casts are traced (`models/registry.py::cast_params`)
 PRECISION_COUNTERS = ("precision.cast_leaves", "precision.cast_bytes")
+# fused-loss backward kernels, as they are traced
+# (`kernels/fused_ce/kernel.py::bwd_grads`)
+LOSS_COUNTERS = ("fused_ce.bwd_sites",)
 _listeners_lock = threading.Lock()
 _listeners_installed = False
 
@@ -150,10 +155,11 @@ def enable(trace: bool = False,
     Returns ``(registry, tracer)`` — the tracer is :data:`NULL_TRACER`
     when tracing stays off.  Call BEFORE constructing the engines /
     schedulers / pools you want instrumented.  The ``compile.*``,
-    ``attn.*`` and ``precision.*`` counters start at 0 in the new
-    registry."""
+    ``attn.*``, ``precision.*`` and ``fused_ce.*`` counters start at 0
+    in the new registry."""
     reg = Registry(enabled=True)
-    for name in COMPILE_COUNTERS + ATTN_COUNTERS + PRECISION_COUNTERS:
+    for name in (COMPILE_COUNTERS + ATTN_COUNTERS + PRECISION_COUNTERS
+                 + LOSS_COUNTERS):
         reg.counter(name)
     tr = Tracer(clock=clock, jax_annotate=jax_annotate) if trace \
         else NULL_TRACER

@@ -499,3 +499,19 @@ def test_enable_twice_registers_the_listeners_once():
     obs.disable()
     assert jm._event_listeners.count(obs._on_event) == 1
     assert jm._event_duration_secs_listeners.count(obs._on_duration) == 1
+
+
+@pytest.mark.parametrize("impl,sites", [("pallas", 1), ("streaming", 0)])
+def test_the_fused_loss_counts_its_backward_kernels(tmp_path, impl, sites):
+    """Each fused-loss backward kernel the train step traces counts once
+    in ``fused_ce.bwd_sites``: one for the Pallas loss, none for the
+    streaming loss, which has no kernel; `launch/train.py
+    --metrics-json` reports the counter."""
+    import json
+    from repro.launch import train
+    out = tmp_path / "metrics.json"
+    train.main(["--arch", "qwen3-0.6b", "--reduced", "--steps", "1",
+                "--global-batch", "2", "--seq-len", "16",
+                "--loss-impl", impl, "--metrics-json", str(out)])
+    got = json.loads(out.read_text())["metrics"]
+    assert got["fused_ce.bwd_sites"]["value"] == sites
