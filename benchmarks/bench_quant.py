@@ -1,6 +1,6 @@
 """Quantized serving: int8 KV paging + quantized lm_head (DESIGN.md §10).
 
-Four claims, each with a deterministic check:
+Three claims, each with a deterministic check:
 
   * **identity** — quantized-paged greedy decode is token-IDENTICAL to
     the quantized slab engine (both decode impls; the pallas kernel's
@@ -17,9 +17,6 @@ Four claims, each with a deterministic check:
     dequantized copy of the int8 K/V pools, the gathered cache, or the
     quantized lm_head (`assert_no_wide_dequant`): dequantization only
     ever happens one VMEM tile at a time inside the kernels.
-  * **plan keys** — int8 and bf16 kernels tune and resolve under
-    distinct tuning-cache keys (``+<wdtype>`` suffix), so plans never
-    cross-contaminate between precisions.
 
 The reduced qwen3 arch is rebuilt with ``head_dim=64`` here: the memory
 claim is about the scale overhead ratio ``(hd + 4) / (2 * hd)``, which
@@ -164,49 +161,11 @@ def check_hlo_hygiene(arch, params, emit, *, smoke):
     del cfg, smoke
 
 
-def check_plan_keys(arch, params, emit, *, smoke):
-    """int8 and bf16 winners live under distinct tuning-cache keys."""
-    import jax.numpy as jnp
-
-    from repro.kernels.paged_attn.autotune import autotune_paged_plan
-    from repro.tuning import get_cache, plan_key
-
-    cfg = arch.cfg
-    nkv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-    kw = dict(b=2, tq=1, nq=cfg.num_heads, nkv=nkv, hd=hd, nb=4,
-              block_size=8, dtype=jnp.bfloat16, trial_budget=2,
-              trial_iters=1)
-    autotune_paged_plan(**kw)
-    autotune_paged_plan(wdtype="int8", **kw)
-    backend = jax.default_backend()
-    k_bf16 = plan_key(2, 32, nkv * hd, "bfloat16", backend, op="pattn8")
-    k_int8 = plan_key(2, 32, nkv * hd, "bfloat16", backend, op="pattn8",
-                      wdtype="int8")
-    cache = get_cache()
-    distinct = (k_bf16 != k_int8 and cache.get(k_bf16) is not None
-                and cache.get(k_int8) is not None)
-    # lm_head kernels namespace the same way (string-level check: the
-    # wdtype rides in the key before the backend, after the op)
-    ce_bf16 = plan_key(8, 512, 64, "bfloat16", backend, op="ce")
-    ce_int8 = plan_key(8, 512, 64, "bfloat16", backend, op="ce",
-                       wdtype="int8")
-    emit("quant_plan_keys", 0.0,
-         f"distinct={int(distinct)},paged_bf16={k_bf16},"
-         f"paged_int8={k_int8}")
-    if smoke:
-        assert distinct, (
-            f"int8/bf16 paged plans share a key or one is missing: "
-            f"{k_bf16!r} vs {k_int8!r}")
-        assert ce_bf16 != ce_int8 and "+int8" in ce_int8, (
-            f"fused-CE key not dtype-namespaced: {ce_int8!r}")
-
-
 def bench_quant(emit, *, smoke: bool = False):
     arch, params = _arch_params(head_dim=64)
     check_identity(arch, params, emit, smoke=smoke)
     check_memory(arch, params, emit, smoke=smoke)
     check_hlo_hygiene(arch, params, emit, smoke=smoke)
-    check_plan_keys(arch, params, emit, smoke=smoke)
 
 
 def main(argv=None):
@@ -224,7 +183,7 @@ def main(argv=None):
         print("smoke OK: quantized paged greedy identical to the "
               "quantized slab + bf16-matched above threshold, <= 0.55x "
               "live bytes/request with scales counted, logits-free and "
-              "wide-dequant-free HLO, precision-distinct plan keys")
+              "wide-dequant-free HLO")
 
 
 if __name__ == "__main__":

@@ -10,8 +10,6 @@ so CI and notebooks consume results without re-parsing the CSV.
   §4.2 throughput  -> bench_train.bench_train_throughput
   Online-topk      -> bench_train.bench_streaming_topk (serving twin)
   §Roofline        -> bench_roofline.bench_roofline_summary (dry-run)
-  §3.2.1 windows   -> bench_autotune.bench_autotune (tuned vs heuristic
-                                                     block plans)
   §5 serving       -> bench_serve.bench_serve (continuous vs fixed-group
                                                batching, logits-free check)
   §6 spec decode   -> bench_spec.bench_spec (speculative vs plain
@@ -32,7 +30,7 @@ so CI and notebooks consume results without re-parsing the CSV.
                                                constrained decoding)
 
 Run:  PYTHONPATH=src python -m benchmarks.run \
-          [--only lat,mem,train,topk,roof,tune,serve,spec,mtp,paged,bwd,obs,modes] \
+          [--only lat,mem,train,topk,roof,serve,spec,mtp,paged,bwd,obs,modes] \
           [--json-dir DIR]
 """
 
@@ -43,7 +41,7 @@ import json
 import os
 import sys
 
-ALL_PARTS = ("lat,mem,train,topk,roof,tune,serve,spec,mtp,paged,quant,"
+ALL_PARTS = ("lat,mem,train,topk,roof,serve,spec,mtp,paged,quant,"
              "bwd,obs,modes")
 
 
@@ -66,9 +64,6 @@ def _runner(part):
     if part == "roof":
         from benchmarks.bench_roofline import bench_roofline_summary
         return [bench_roofline_summary]
-    if part == "tune":
-        from benchmarks.bench_autotune import bench_autotune
-        return [bench_autotune]
     if part == "serve":
         from benchmarks.bench_serve import bench_serve
         return [bench_serve]

@@ -219,13 +219,13 @@ def _kernel_loss(cfg, y):
     import jax.numpy as jnp
     from repro.core import fused_cross_entropy
     from repro.core.streaming import _row_scale
+    from repro.core.windows import choose_blocks
     from repro.kernels.fused_ce import kernel as K
-    from repro.kernels.fused_ce.autotune import lookup_plan
 
     @jax.jit
     def run(h, w):
-        plan = lookup_plan(h.shape[0], w.shape[0], h.shape[-1], h.dtype,
-                           cfg=cfg)
+        plan = choose_blocks(h.shape[0], w.shape[0], h.shape[-1],
+                             in_bytes=h.dtype.itemsize)
         loss = fused_cross_entropy(h, w, y, impl="pallas", cfg=cfg)
         lse, _, _ = K.fwd_stats(h, w, y, cfg, plan=plan)
         gamma = _row_scale(jnp.float32(1.0), y, cfg)
@@ -436,8 +436,6 @@ def main(argv=None) -> int:
                     help="run only the (1, 4)-mesh sharded loss and what "
                          "it is compared with, on four chips of one host")
     args = ap.parse_args(argv)
-    # plans come from the block heuristic, not from a tuning file
-    os.environ.setdefault("REPRO_TUNING_CACHE", "off")
     try:
         import jax
         from repro.launch.compile_cache import use_compile_cache

@@ -11,7 +11,7 @@ Two bug classes this repo has actually hit:
   * Non-pure ``BlockSpec`` index-map lambdas: an index map must be a
     pure function of the grid indices.  Flagged are (a) ``program_id``
     calls inside the lambda (the grid position is the lambda's
-    *argument*, querying it inside is wrong under autotuned grids) and
+    *argument*, querying it inside is wrong under another grid) and
     (b) late binding — a lambda built inside a ``for`` loop that closes
     over the loop variable, so every spec ends up using the *last*
     iteration's value.

@@ -9,38 +9,12 @@ dry-run (no allocation).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.types import LossConfig
-
-# ---------------------------------------------------------------------------
-# kernel tuning
-# ---------------------------------------------------------------------------
-
-
-@dataclasses.dataclass(frozen=True)
-class TuningConfig:
-    """Block-plan autotuning knobs (DESIGN.md §3.2).
-
-    Attributes:
-      enabled: run the empirical autotuner for the fused-CE kernels; when
-        False every call site falls back to the `choose_blocks` heuristic.
-      cache_path: persistent JSON cache location.  None → the default
-        (``$REPRO_TUNING_CACHE`` or ``~/.cache/repro/blockplans.json``);
-        ``""`` → process-local in-memory cache (no persistence).
-      trial_budget: max candidate plans timed per (shape, dtype, backend)
-        key; <= 0 disables measurement (heuristic only).
-      trial_iters: timed iterations per candidate (the min is kept).
-    """
-
-    enabled: bool = False
-    cache_path: Optional[str] = None
-    trial_budget: int = 8
-    trial_iters: int = 2
-
 
 # ---------------------------------------------------------------------------
 # multi-token prediction (DESIGN.md §7)

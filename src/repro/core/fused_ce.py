@@ -65,10 +65,10 @@ def fused_cross_entropy(
         marking masked positions.
       impl: one of 'auto' | 'canonical' | 'streaming' | 'pallas'.
       cfg: LossConfig (reduction, label smoothing, z-loss, softcap, padding).
-      plan: optional tuned `BlockPlan` (DESIGN.md §3) — the Pallas tile
-        shape / streaming window.  Ignored by 'canonical' (no tiling);
-        `None` lets each impl resolve its own default (pallas consults the
-        tuning cache).
+      plan: optional `BlockPlan` (DESIGN.md §3) — the Pallas tile shape /
+        streaming window.  Ignored by 'canonical' (no tiling); `None` lets
+        each impl resolve its own default (pallas: `choose_blocks` of the
+        shape).
 
     Returns:
       scalar loss ('mean'/'sum') or per-row losses ('none').

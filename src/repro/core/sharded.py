@@ -31,7 +31,6 @@ kernels with global column ids via `col_offset`).
 
 from __future__ import annotations
 
-import dataclasses
 from typing import Optional, Sequence
 
 import jax
@@ -40,14 +39,13 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core.types import LossConfig
-from repro.core.windows import BlockPlan
 from repro.core.streaming import (
     streaming_stats, streaming_grads, _rows_from_stats)
 
 Mesh = jax.sharding.Mesh
 
 
-def _local_stats(h, w, y, cfg, impl, col_offset, total_valid, plan=None,
+def _local_stats(h, w, y, cfg, impl, col_offset, total_valid,
                  return_tile_stats=False):
     """Per-shard forward stats; with `return_tile_stats` a fourth output
     carries the grad-filter tile maxima (DESIGN.md §9), normalized to a
@@ -55,7 +53,7 @@ def _local_stats(h, w, y, cfg, impl, col_offset, total_valid, plan=None,
     a single row block spanning all local rows."""
     if impl == "pallas":
         from repro.kernels.fused_ce.kernel import fwd_stats
-        return fwd_stats(h, w, y, cfg, plan=plan, col_offset=col_offset,
+        return fwd_stats(h, w, y, cfg, col_offset=col_offset,
                          total_valid=total_valid,
                          return_tile_stats=return_tile_stats)
     out = streaming_stats(h, w, y, cfg, col_offset=col_offset,
@@ -68,14 +66,14 @@ def _local_stats(h, w, y, cfg, impl, col_offset, total_valid, plan=None,
 
 
 def _local_grads(h, w, y, lse, gamma, p_coeff, cfg, impl, col_offset,
-                 total_valid, plan=None, tile_stats=None):
+                 total_valid, tile_stats=None):
     """Per-shard backward; `tile_stats` (when filtering) is this shard's
     LOCAL tile-max panel — the skip mask is derived against the globally
     combined `lse` with the shard's own `col_offset`, so a target owned
     by another shard never pins a tile here."""
     if impl == "pallas":
         from repro.kernels.fused_ce.kernel import bwd_grads
-        return bwd_grads(h, w, y, lse, gamma, p_coeff, cfg, plan=plan,
+        return bwd_grads(h, w, y, lse, gamma, p_coeff, cfg,
                          col_offset=col_offset, total_valid=total_valid,
                          tile_stats=tile_stats)
     # streaming_grads folds p_coeff internally from (gamma, z_loss, lse)
@@ -106,7 +104,6 @@ def make_sharded_loss(
     vocab_axis: str = "model",
     layout: str = "2d",
     impl: str = "streaming",
-    plan: Optional[BlockPlan] = None,
 ):
     """Build a differentiable sharded fused-CE:  f(h, w, y) -> scalar loss.
 
@@ -119,16 +116,11 @@ def make_sharded_loss(
 
     reduction must be 'mean' or 'sum' (a global scalar).
 
-    `plan` is the per-shard block plan (DESIGN.md §3.2): every device
-    streams its LOCAL (rows_local × vocab_local) panel, so tune/key on the
-    local shapes — rows_local = N / prod(rows_axes) and
-    vocab_local = V / mesh.shape[vocab_axis] — not the global ones.
-    For impl='streaming' only `plan.block_v` applies (window size);
-    for impl='pallas' it sets the kernel tile shape.
+    Every device streams its LOCAL (rows_local × vocab_local) panel, and
+    the Pallas kernels tile it with `choose_blocks` of that local shape
+    (DESIGN.md §3.2); the streaming impl scans `cfg.block_v` windows.
     """
     cfg = cfg or LossConfig()
-    if plan is not None and impl == "streaming":
-        cfg = dataclasses.replace(cfg, block_v=plan.block_v)
     if cfg.reduction not in ("mean", "sum"):
         raise ValueError("sharded loss requires a scalar reduction")
     if layout not in ("2d", "sp_gather"):
@@ -163,7 +155,7 @@ def make_sharded_loss(
         total_valid = cfg.resolve_vocab(v_local * n_vocab_shards)
         stats = _local_stats(
             h_l, w_l, y_l, cfg, impl, _offset(v_local), total_valid,
-            plan=plan, return_tile_stats=filtering)
+            return_tile_stats=filtering)
         lse_p, zt_p, zs_p = stats[:3]
         lse = _combine_lse(lse_p, vocab_axis)
         z_tgt = jax.lax.psum(zt_p, vocab_axis)
@@ -207,7 +199,7 @@ def make_sharded_loss(
         p_coeff = gamma_l * (1.0 + 2.0 * jnp.float32(cfg.z_loss) * lse_l)
         dh_p, dw_l = _local_grads(
             h_l, w_l, y_l, lse_l, gamma_l, p_coeff, cfg, impl,
-            _offset(v_local), total_valid, plan=plan, tile_stats=tmax_l)
+            _offset(v_local), total_valid, tile_stats=tmax_l)
         if layout == "sp_gather":
             # reduce-scatter dH back to the SP layout (paper Fig 3c reverse)
             dh = jax.lax.psum_scatter(dh_p, vocab_axis, scatter_dimension=0,

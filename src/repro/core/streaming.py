@@ -321,7 +321,7 @@ def streaming_loss(
       w: (V_padded, d) lm_head weights.
       y: (N,) int targets.
       cfg: loss configuration (`block_v` is the paper's window size).
-      plan: optional tuned `BlockPlan` (DESIGN.md §3.2); the scan streams
+      plan: optional `BlockPlan` (DESIGN.md §3.2); the scan streams
         whole rows, so only `plan.block_v` applies — it overrides
         `cfg.block_v` as the window size.
     """

@@ -17,10 +17,10 @@ buffering.  MXU efficiency wants every matmul dim to be a multiple of 128
 (lanes) and the sublane dim a multiple of 8.  `choose_blocks` encodes that
 napkin math so callers never hand-tune (DESIGN.md §3.1).
 
-`choose_blocks` is also the cold-cache fallback of the empirical
-autotuner (`repro.kernels.fused_ce.autotune`, DESIGN.md §3.2), which
-measures candidate plans with the real kernels and memoizes the winner
-in the persistent tuning cache (`repro.tuning`).
+`choose_blocks` is the one owner of the vocabulary kernels' tile plans
+(fused CE, top-k, token scoring): a pure function of the problem's shape
+and itemsize, decided at trace time and never measured or cached
+(DESIGN.md §3.2).
 """
 
 from __future__ import annotations

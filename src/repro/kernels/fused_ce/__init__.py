@@ -2,8 +2,5 @@
 
 from repro.kernels.fused_ce.ops import pallas_loss
 from repro.kernels.fused_ce.kernel import fwd_stats, bwd_grads
-from repro.kernels.fused_ce.autotune import (autotune_plan, candidate_plans,
-                                             lookup_plan, run_trials)
 
-__all__ = ["pallas_loss", "fwd_stats", "bwd_grads", "autotune_plan",
-           "candidate_plans", "lookup_plan", "run_trials"]
+__all__ = ["pallas_loss", "fwd_stats", "bwd_grads"]

@@ -6,9 +6,9 @@ custom_vjp structure), with the vocab streaming executed by the TPU kernels
 in `kernel.py` instead of a `lax.scan`.
 
 Block-plan selection (DESIGN.md §3): callers may pass an explicit
-`BlockPlan`; when they don't, the plan is resolved through the persistent
-tuning cache — the autotuned winner for this exact (shape, dtype, backend)
-when one has been recorded, else the `choose_blocks` heuristic.
+`BlockPlan`; when they don't, the plan is `choose_blocks` of the problem's
+shape and the hidden states' dtype — a pure function, resolved at trace
+time, that both kernels share.
 """
 
 from __future__ import annotations
@@ -23,9 +23,8 @@ import numpy as np
 from repro.core.types import LossConfig
 from repro.core.canonical import reduce_loss
 from repro.core.streaming import _rows_from_stats, _row_scale
-from repro.core.windows import BlockPlan
+from repro.core.windows import BlockPlan, choose_blocks
 from repro.kernels.fused_ce import kernel as K
-from repro.kernels.fused_ce.autotune import lookup_plan
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -78,22 +77,21 @@ def pallas_loss(
     On non-TPU backends the kernels run in interpret mode (Python reference
     execution of the kernel body) — bit-for-bit the same algorithm.
 
-    `plan` fixes the kernel tiling; `None` resolves it through the tuning
-    cache (tuned winner if this shape was autotuned, `choose_blocks`
-    otherwise).  Resolution is a trace-time dict lookup, never a trial run.
+    `plan` fixes the kernel tiling; `None` takes `choose_blocks` of the
+    shape at the hidden states' itemsize, passed to the forward and the
+    backward alike (the forward's own fallback sizes by `w`, which is
+    1-byte for a quantized head).
 
     `w_scale` (V,) f32 marks `w` as row-quantized
     (`kernels/quant.quantize_weight`): the forward streams 1-byte W
-    tiles with in-register rescale and plans resolve under the
-    wdtype-namespaced cache key.  This path is forward/eval only (no
+    tiles with in-register rescale.  This path is forward/eval only (no
     custom VJP) — differentiating through it fails, by design: training
     keeps a full-precision master weight (DESIGN.md §10.2).
     """
     cfg = cfg or LossConfig()
     if plan is None:
-        wdtype = w.dtype.name if w_scale is not None else None
-        plan = lookup_plan(h.shape[0], w.shape[0], h.shape[-1], h.dtype,
-                           cfg=cfg, wdtype=wdtype)
+        plan = choose_blocks(h.shape[0], w.shape[0], h.shape[-1],
+                             in_bytes=h.dtype.itemsize)
     if w_scale is not None:
         lse, z_tgt, z_sum = K.fwd_stats(h, w, y, cfg, plan=plan,
                                         w_scale=w_scale)

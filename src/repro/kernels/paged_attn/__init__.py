@@ -1,7 +1,3 @@
 from repro.kernels.paged_attn.kernel import pallas_paged_attention
-from repro.kernels.paged_attn.autotune import (autotune_paged_plan,
-                                               lookup_paged_plan,
-                                               plan_pages_per_step)
 
-__all__ = ["pallas_paged_attention", "autotune_paged_plan",
-           "lookup_paged_plan", "plan_pages_per_step"]
+__all__ = ["pallas_paged_attention"]

@@ -24,11 +24,10 @@ masking is per-row absolute-position causal: query ``i`` of ``Tq`` sits
 at ``lens[b] - Tq + i`` (``Tq > 1`` is the speculative-verification
 path).  Ghost rows (``lens == 0``) mask everything and emit zeros.
 
-``pages_per_step`` (ppb) is the tunable: how many pool blocks one
-sequential grid step fetches (more DMAs in flight per step).  It is
-resolved through the shared BlockPlan machinery — `autotune.py` maps
-``BlockPlan.block_v`` to ``ppb = block_v // block_size`` and memoizes
-winners in the persistent tuning cache under ``pattn<block_size>`` keys.
+``pages_per_step`` (ppb) is how many pool blocks one sequential grid
+step fetches (more DMAs in flight per step).  The serving path runs one
+page a step (DESIGN.md §3.2); the argument stays for callers and tests
+that sweep it.
 
 `models/attention.py`'s gather-based `decode_attention` path is the
 pure-jnp oracle (`tests/test_paged_attn.py` holds the equivalence).

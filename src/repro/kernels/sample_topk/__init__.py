@@ -2,9 +2,5 @@
 
 from repro.kernels.sample_topk.ops import pallas_topk
 from repro.kernels.sample_topk.kernel import topk_scores
-from repro.kernels.sample_topk.autotune import (autotune_topk_plan,
-                                                lookup_topk_plan,
-                                                run_topk_trials)
 
-__all__ = ["pallas_topk", "topk_scores", "autotune_topk_plan",
-           "lookup_topk_plan", "run_topk_trials"]
+__all__ = ["pallas_topk", "topk_scores"]

@@ -12,8 +12,8 @@ kernel shares the fused-CE structure wholesale —
   * the same masking convention: a column is valid iff it is structurally
     real (``local_col < V_orig``) and its global id (``local + offset``)
     is ``< valid_vocab``;
-  * `BlockPlan` tiling resolved through the same autotune/cache stack
-    (`kernels/sample_topk/autotune.py`, cache key namespaced ``topk<k>``).
+  * the same `BlockPlan` tiling, `choose_blocks` of the shape unless the
+    caller passes a plan.
 
 Instead of online-softmax scalars, the carried VMEM scratch is the running
 per-row top-k — ``(block_rows, k_pad)`` values (f32) and global indices

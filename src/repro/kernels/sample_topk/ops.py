@@ -2,9 +2,8 @@
 
 `pallas_topk(h, w, k)` mirrors `fused_ce.ops.pallas_loss`: callers may fix
 the kernel tiling with an explicit `BlockPlan`; when they don't, the plan
-resolves through the persistent tuning cache (the autotuned winner for
-this exact (rows, vocab, d, k, dtype, backend) when recorded, else the
-`choose_blocks` heuristic).  Resolution is a trace-time dict lookup.
+is `choose_blocks` of the (rows, vocab, d) shape at the hidden states'
+itemsize, resolved at trace time.
 
 No custom VJP: sampling is not differentiated through.
 """
@@ -15,9 +14,8 @@ from typing import Optional
 
 import jax
 
-from repro.core.windows import BlockPlan
+from repro.core.windows import BlockPlan, choose_blocks
 from repro.kernels.sample_topk import kernel as K
-from repro.kernels.sample_topk.autotune import lookup_topk_plan
 
 
 def pallas_topk(
@@ -41,22 +39,16 @@ def pallas_topk(
     dense logits exactly at every finite position, ties included (-inf
     tail positions, k > valid vocab, carry unspecified indices).
 
-    `w_scale` (V,) marks `w` as row-quantized (`quantize_weight`); plans
-    then resolve under the wdtype-namespaced cache key so int8 and bf16
-    winners never shadow each other.
+    `w_scale` (V,) marks `w` as row-quantized (`quantize_weight`).
 
     `allowed_mask` (B, V) constrains candidates to the nonzero-mask set
-    (constrained decoding, DESIGN.md §12.3); plans then resolve under the
-    ``+mask``-suffixed op key — streaming the extra (bm, bv) mask tile
-    shifts the tile-size optimum, so masked and unmasked winners never
-    mix.  `return_lse=True` appends the per-row logsumexp (B,) over the
-    same filtered logits (beam-search logprobs from one vocab scan).
+    (constrained decoding, DESIGN.md §12.3).  `return_lse=True` appends
+    the per-row logsumexp (B,) over the same filtered logits (beam-search
+    logprobs from one vocab scan).
     """
     if plan is None:
-        wdtype = w.dtype.name if w_scale is not None else None
-        plan = lookup_topk_plan(h.shape[0], w.shape[0], h.shape[-1], k,
-                                h.dtype, wdtype=wdtype,
-                                masked=allowed_mask is not None)
+        plan = choose_blocks(h.shape[0], w.shape[0], h.shape[-1],
+                             in_bytes=h.dtype.itemsize)
     return K.topk_scores(h, w, k, valid_vocab=valid_vocab,
                          logit_softcap=logit_softcap, plan=plan,
                          interpret=interpret, col_offset=col_offset,

@@ -3,9 +3,5 @@
 from repro.kernels.score_tokens.ops import pallas_score_tokens
 from repro.kernels.score_tokens.kernel import score_stats
 from repro.kernels.score_tokens.ref import streaming_score
-from repro.kernels.score_tokens.autotune import (autotune_score_plan,
-                                                 lookup_score_plan,
-                                                 run_score_trials)
 
-__all__ = ["pallas_score_tokens", "score_stats", "streaming_score",
-           "autotune_score_plan", "lookup_score_plan", "run_score_trials"]
+__all__ = ["pallas_score_tokens", "score_stats", "streaming_score"]

@@ -2,10 +2,8 @@
 
 `pallas_score_tokens(h, w, ids)` mirrors `sample_topk.ops.pallas_topk`:
 callers may fix the tiling with an explicit `BlockPlan`; when they
-don't, the plan resolves through the persistent tuning cache (the
-autotuned winner for this exact (rows, vocab, d, P, dtype, backend)
-when recorded, else the `choose_blocks` heuristic).  Resolution is a
-trace-time dict lookup.
+don't, the plan is `choose_blocks` of the (rows, vocab, d) shape at the
+hidden states' itemsize, resolved at trace time.
 
 No custom VJP: scoring/verification is not differentiated through.
 """
@@ -17,9 +15,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.windows import BlockPlan
+from repro.core.windows import BlockPlan, choose_blocks
 from repro.kernels.score_tokens import kernel as K
-from repro.kernels.score_tokens.autotune import lookup_score_plan
 
 
 def pallas_score_tokens(
@@ -38,8 +35,7 @@ def pallas_score_tokens(
     """(logp (N, P) f32, lse (N,) f32) of candidate ids — logits-free.
 
     `w_scale` (V,) marks `w` as row-quantized (`quantize_weight`): the
-    kernel streams 1-byte W tiles with in-register rescale, and plans
-    resolve under the wdtype-namespaced tuning-cache key.
+    kernel streams 1-byte W tiles with in-register rescale.
 
     ``logp[r, p] = log softmax(h_r @ w.T)[ids[r, p]]`` on the valid
     vocabulary (softcap, then 1/T temperature scaling, applied inside
@@ -56,9 +52,8 @@ def pallas_score_tokens(
     if squeeze:
         ids = ids[:, None]
     if plan is None:
-        wdtype = w.dtype.name if w_scale is not None else None
-        plan = lookup_score_plan(h.shape[0], w.shape[0], h.shape[-1],
-                                 ids.shape[1], h.dtype, wdtype=wdtype)
+        plan = choose_blocks(h.shape[0], w.shape[0], h.shape[-1],
+                             in_bytes=h.dtype.itemsize)
     lse, zt = K.score_stats(h, w, ids, valid_vocab=valid_vocab,
                             logit_softcap=logit_softcap,
                             temperature=temperature, plan=plan,

@@ -68,8 +68,6 @@ def run(argv=None) -> ServeRun:
     ap.add_argument("--top-p", type=float, default=None)
     ap.add_argument("--sampler-impl", default="pallas",
                     choices=("pallas", "jax"))
-    ap.add_argument("--autotune", action="store_true",
-                    help="tune decode top-k block plans at engine init")
     ap.add_argument("--spec-draft", default=None,
                     help="draft arch id for speculative decoding "
                          "('self': draft with the target model)")
@@ -174,7 +172,7 @@ def run(argv=None) -> ServeRun:
     sc = ServeConfig(batch_size=args.batch, max_len=args.max_len,
                      temperature=args.temperature, top_k=args.top_k,
                      top_p=args.top_p, sampler_impl=args.sampler_impl,
-                     enc_len=enc_len, autotune=args.autotune,
+                     enc_len=enc_len,
                      paged=args.paged, block_size=args.block_size,
                      pool_blocks=args.pool_blocks,
                      paged_impl=args.paged_impl,

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.checkpoint import Checkpointer
-from repro.configs.base import TuningConfig, with_mtp
+from repro.configs.base import with_mtp
 from repro.data import DataConfig, SyntheticLM, ShardedLoader
 from repro.distributed.fault import PreemptionHandler, StragglerMonitor
 from repro.launch.compile_cache import use_compile_cache
@@ -40,7 +40,6 @@ from repro.models.registry import get_arch
 from repro.sharding.rules import AxisRules
 from repro.train import (TrainConfig, build_train_step, train_loop,
                          resume_or_init, state_shardings)
-from repro.train.step import make_tuning_prewarm
 
 
 class TrainRun(NamedTuple):
@@ -79,12 +78,6 @@ def run(argv=None) -> TrainRun:
     ap.add_argument("--mtp-weights", default=None,
                     help="comma-separated per-head loss weights "
                          "(default: 1.0 each)")
-    ap.add_argument("--autotune", action="store_true",
-                    help="empirically tune the fused-CE block plan at "
-                         "startup (memoized in the tuning cache)")
-    ap.add_argument("--tuning-cache", default=None,
-                    help="tuning-cache JSON path ('' = in-memory only; "
-                         "default: $REPRO_TUNING_CACHE or ~/.cache/repro)")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--log-every", type=int, default=10)
@@ -139,9 +132,7 @@ def run(argv=None) -> TrainRun:
         loss_impl=args.loss_impl,
         loss_block_v=min(2048, arch.padded_vocab),
         grad_accum=args.grad_accum,
-        grad_filter_eps=args.grad_filter_eps,
-        tuning=TuningConfig(enabled=args.autotune,
-                            cache_path=args.tuning_cache))
+        grad_filter_eps=args.grad_filter_eps)
     init_fn, step_fn = build_train_step(arch, tc, rules)
 
     ck = Checkpointer(args.ckpt_dir) if args.ckpt_dir else None
@@ -163,17 +154,11 @@ def run(argv=None) -> TrainRun:
                     global_batch=args.global_batch, seed=args.seed)
     loader = ShardedLoader(SyntheticLM(dc), mesh=mesh)
 
-    on_start = None
-    if args.autotune:
-        on_start = make_tuning_prewarm(
-            arch, tc, n_rows=args.global_batch * args.seq_len, rules=rules)
-
     state, history = train_loop(
         state=state, step_fn=jstep, data=loader, num_steps=args.steps,
         checkpointer=ck, checkpoint_every=args.ckpt_every,
         log_every=args.log_every,
-        preemption=PreemptionHandler(), straggler=StragglerMonitor(),
-        on_start=on_start)
+        preemption=PreemptionHandler(), straggler=StragglerMonitor())
     if history:
         first = history[0][1]["loss"]
         last = history[-1][1]["loss"]

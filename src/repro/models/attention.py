@@ -542,17 +542,12 @@ def attention_layer(
                                            gather_paged_kv(vp, table),
                                            new_len, cfg)
             elif cfg.paged_impl == "pallas":
-                from repro.kernels.paged_attn import (lookup_paged_plan,
-                                                      pallas_paged_attention)
-                ppb = lookup_paged_plan(
-                    b, t, kp.shape[2], kp.shape[3], table.shape[1],
-                    kp.shape[1], q.dtype,
-                    wdtype=str(kp.dtype) if quant else None)
+                from repro.kernels.paged_attn import pallas_paged_attention
                 out = pallas_paged_attention(
                     q, kp, vp, table, new_len,
                     kp_scale=kps if quant else None,
                     vp_scale=vps if quant else None,
-                    softcap=cfg.attn_softcap, pages_per_step=ppb)
+                    softcap=cfg.attn_softcap, pages_per_step=1)
             elif quant:
                 # pure-jnp oracle: gather the chains into a dense
                 # quantized-slab view and reuse the slab decode math

@@ -1,8 +1,8 @@
 """`repro.obs` — dependency-free tracing + metrics (DESIGN.md §11).
 
 The measurement seam for the whole stack: the scheduler, engines, block
-pool, plan tuner, and train loop bind instruments from the PROCESS
-defaults exposed here.  Both default to disabled — a no-op
+pool and train loop bind instruments from the PROCESS defaults exposed
+here.  Both default to disabled — a no-op
 :class:`~repro.obs.metrics.Registry` and the shared
 :data:`~repro.obs.trace.NULL_TRACER` — so instrumentation costs a
 no-op method call until something opts in:

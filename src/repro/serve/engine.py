@@ -61,8 +61,6 @@ class ServeConfig:
     sampler_impl: str = "pallas"   # 'pallas' kernel | 'jax' oracle
     bucket_prefill: bool = True    # pow2 prompt buckets (all families)
     enc_len: Optional[int] = None  # enc-dec encoder frames per request
-    autotune: bool = False         # tune decode top-k block plans at init
-    tune_trial_budget: int = 6
     # paged KV cache (serve/paged.PagedEngine, DESIGN.md §8)
     paged: bool = False            # block-pool KV instead of dense slabs
     block_size: int = 16           # tokens per pool block
@@ -232,9 +230,6 @@ class Engine:
         self._slot_masks: Dict[int, np.ndarray] = {}
         self._modefns = None
 
-        if sc.autotune:
-            self._tune_plans()
-
         prefill, prefill_ext, decode = build_serve_fns(arch, sc)
         wrap = jax.jit if jit else (lambda f, **kw: f)
         # donate the batched cache operand so decode/insert/reset update it
@@ -292,21 +287,6 @@ class Engine:
         self._rng = jax.random.PRNGKey(seed)
         if getattr(self, "_slot_masks", None):
             self._slot_masks.clear()
-
-    def _tune_plans(self):
-        """Populate the tuning cache for the decode/prefill sample shapes
-        BEFORE the first trace, mirroring the train-side tune-at-startup."""
-        from repro.kernels.sample_topk import autotune_topk_plan
-        k = 1 if self.sc.temperature == 0.0 else self.sc.top_k
-        v, d = self.params["lm_head"].shape
-        dtype = jnp.dtype(getattr(self.arch.cfg, "compute_dtype",
-                                  "float32"))
-        for n in sorted({1, self.sc.batch_size}):
-            autotune_topk_plan(
-                n, v, d, k, dtype,
-                trial_budget=self.sc.tune_trial_budget,
-                logit_softcap=resolve_logit_softcap(self.arch, self.sc),
-                wdtype=self._head_dtype)
 
     # -- slot operations ----------------------------------------------------
 

@@ -77,8 +77,6 @@ class _PagedMixin:
         self._merge = wrap(
             lambda caches, slot_caches, slot:
             merge_slot_caches(caches, slot_caches, slot, axes), **dn)
-        if sc.autotune and self._n_paged:
-            self._tune_paged_plans()
 
     # -- hooks into Engine ---------------------------------------------------
 
@@ -354,21 +352,7 @@ class _PagedMixin:
             self._tables[slot, :] = kvpool.NULL_BLOCK
             self._tables_dirty = True
 
-    # -- autotune / reporting ------------------------------------------------
-
-    def _tune_paged_plans(self, tqs=(1,)):
-        cfg = self.arch.cfg
-        if not hasattr(cfg, "num_kv_heads"):
-            return
-        from repro.kernels.paged_attn import autotune_paged_plan
-        nkv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-        for tq in sorted(set(tqs)):
-            autotune_paged_plan(
-                self.sc.batch_size, tq, cfg.num_heads, nkv, hd,
-                self._pc.max_blocks_per_slot, self._pc.block_size,
-                jnp.dtype(getattr(cfg, "compute_dtype", "float32")),
-                trial_budget=self.sc.tune_trial_budget,
-                wdtype="int8" if self._quant else None)
+    # -- reporting -----------------------------------------------------------
 
     def paged_stats(self) -> Dict[str, Any]:
         out: Dict[str, Any] = {"enabled": bool(self._n_paged)}

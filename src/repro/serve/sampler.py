@@ -8,9 +8,9 @@ Two implementations share one contract:
     `lax.scan`, keeping a running (values, indices) top-k.  Runs on any
     backend; serves as the semantic oracle for the kernel.
   * `repro.kernels.sample_topk.pallas_topk` — the Pallas TPU kernel with
-    the same VMEM online-scan structure, BlockPlan tiling, and autotune
-    integration as the fused-CE forward (DESIGN.md §5.3).  Bit-identical
-    to the oracle at every finite position, ties included.
+    the same VMEM online-scan structure and BlockPlan tiling as the
+    fused-CE forward (DESIGN.md §5.3).  Bit-identical to the oracle at
+    every finite position, ties included.
 
 `sample_tokens` draws greedy (temperature == 0) or temperature/top-k/
 top-p samples from the surviving k logits.  `logit_softcap` applies the
@@ -29,8 +29,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-from repro.core.windows import BlockPlan
 
 
 def streaming_topk(
@@ -154,16 +152,16 @@ def sample_tokens(
     top_p: Optional[float] = None,
     block_v: int = 8192, valid_vocab: Optional[int] = None,
     logit_softcap: Optional[float] = None,
-    impl: str = "pallas", plan: Optional[BlockPlan] = None,
+    impl: str = "pallas",
     w_scale: Optional[jax.Array] = None,
     allowed_mask: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Next-token ids (B,) — greedy when temperature == 0.
 
     impl: 'pallas' (streaming Pallas kernel, interpret mode off-TPU) or
-    'jax' (the pure-JAX `streaming_topk` oracle).  `plan` pins the kernel
-    tiling; None resolves it through the tuning cache.  `w_scale` marks
-    `w` as a row-quantized lm_head (`ServeConfig.head_dtype`).
+    'jax' (the pure-JAX `streaming_topk` oracle); the kernel tiles by
+    `choose_blocks` of the shape.  `w_scale` marks `w` as a row-quantized
+    lm_head (`ServeConfig.head_dtype`).
     `allowed_mask` (B, V) restricts sampling to the nonzero-mask token
     set per row (constrained/JSON decoding): disallowed tokens score
     -inf inside the vocab scan and can never be drawn at any temperature
@@ -173,7 +171,7 @@ def sample_tokens(
     if impl == "pallas":
         from repro.kernels.sample_topk import pallas_topk
         vals, idxs = pallas_topk(h, w, k, valid_vocab=valid_vocab,
-                                 logit_softcap=logit_softcap, plan=plan,
+                                 logit_softcap=logit_softcap,
                                  w_scale=w_scale,
                                  allowed_mask=allowed_mask)
     elif impl == "jax":

@@ -275,41 +275,17 @@ class SpecEngine(Engine):
                  spec: Optional[SpecConfig] = None, jit: bool = True):
         self.spec = spec or SpecConfig()
         super().__init__(arch, params, sc, jit=jit)
-        dsc = dataclasses.replace(sc, autotune=False)
-        self.draft = Engine(draft_arch, draft_params, dsc, jit=jit)
+        self.draft = Engine(draft_arch, draft_params, sc, jit=jit)
         self.draft_arch = draft_arch
         step = build_spec_step(arch, draft_arch, sc, self.spec,
                                self._axes, self.draft._axes)
         dn = ({"donate_argnums": (2, 3)}
               if jit and jax.default_backend() != "cpu" else {})
         self._spec_step = jax.jit(step, **dn) if jit else step
-        if sc.autotune:
-            self._tune_spec_plans()
 
     @property
     def spec_k(self) -> int:
         return self.spec.k
-
-    def _tune_spec_plans(self):
-        """Tune the verify-path kernels for their exact shapes BEFORE the
-        first trace: top-k over B*(K+1) rows, and — in rejection mode
-        only, greedy acceptance never scores — scoring over B*K rows."""
-        from repro.kernels.sample_topk import autotune_topk_plan
-        from repro.kernels.score_tokens import autotune_score_plan
-        b, kk = self.sc.batch_size, self.spec.k
-        v, d = self.params["lm_head"].shape
-        dtype = jnp.dtype(getattr(self.arch.cfg, "compute_dtype",
-                                  "float32"))
-        cap = resolve_logit_softcap(self.arch, self.sc)
-        topk = 1 if self.sc.temperature == 0.0 else self.sc.top_k
-        autotune_topk_plan(b * (kk + 1), v, d, topk, dtype,
-                           trial_budget=self.sc.tune_trial_budget,
-                           logit_softcap=cap, wdtype=self._head_dtype)
-        if self.sc.temperature != 0.0:
-            autotune_score_plan(b * kk, v, d, 1, dtype,
-                                trial_budget=self.sc.tune_trial_budget,
-                                logit_softcap=cap,
-                                wdtype=self._head_dtype)
 
     # -- lifecycle (both cache trees) ---------------------------------------
 
@@ -565,34 +541,10 @@ class SelfSpecEngine(Engine):
         self._spec_step = wrap(step, **dn)
         self._prefill_mtp = wrap(prefill)
         self._prefill_mtp_ext = wrap(prefill_ext)
-        if sc.autotune:
-            self._tune_self_spec_plans()
 
     @property
     def spec_k(self) -> int:
         return self.spec.k
-
-    def _tune_self_spec_plans(self):
-        """Tune the verify/redraft kernels for their exact shapes before
-        the first trace: top-k over B*(K+1) choice rows and B*K head-
-        draft rows; scoring over B*K rows in rejection mode only."""
-        from repro.kernels.sample_topk import autotune_topk_plan
-        from repro.kernels.score_tokens import autotune_score_plan
-        b, kk = self.sc.batch_size, self.spec.k
-        v, d = self.params["lm_head"].shape
-        dtype = jnp.dtype(getattr(self.arch.cfg, "compute_dtype",
-                                  "float32"))
-        cap = resolve_logit_softcap(self.arch, self.sc)
-        topk = 1 if self.sc.temperature == 0.0 else self.sc.top_k
-        for n in sorted({b * (kk + 1), b * kk}):
-            autotune_topk_plan(n, v, d, topk, dtype,
-                               trial_budget=self.sc.tune_trial_budget,
-                               logit_softcap=cap, wdtype=self._head_dtype)
-        if self.sc.temperature != 0.0:
-            autotune_score_plan(b * kk, v, d, 1, dtype,
-                                trial_budget=self.sc.tune_trial_budget,
-                                logit_softcap=cap,
-                                wdtype=self._head_dtype)
 
     # -- lifecycle (adds the per-slot pending-draft state) -------------------
 

@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import logging
 import time
-from typing import Any, Callable, Dict, Iterable, Optional
+from typing import Callable, Dict, Iterable, Optional
 
 import jax
 import numpy as np
@@ -34,14 +34,8 @@ def train_loop(
     preemption: Optional[PreemptionHandler] = None,
     straggler: Optional[StragglerMonitor] = None,
     metrics_hook: Optional[Callable[[int, Dict[str, float]], None]] = None,
-    on_start: Optional[Callable[[], Any]] = None,
 ):
     """Runs up to `num_steps` steps; returns (state, history).
-
-    `on_start` is a one-time startup hook run before the first step — the
-    intended use is block-plan autotuning (`train.step.make_tuning_prewarm`)
-    so kernel trial timing happens once here, outside the recorded per-step
-    timings; its wall time is logged separately.
 
     Each step is a `train.step` span (a `StepTraceAnnotation` when the
     tracer bridges to the profiler) holding `train.feed` (the loader's
@@ -62,12 +56,6 @@ def train_loop(
     m_steps = reg.counter("train.steps_total", help="optimizer steps run")
     m_tokens = reg.counter("train.tokens_total",
                            help="tokens consumed by training")
-
-    if on_start is not None:
-        t0 = time.perf_counter()
-        on_start()
-        log.info("startup hook finished in %.2fs",
-                 time.perf_counter() - t0)
 
     it = iter(data)
     for i in range(start_step, num_steps):

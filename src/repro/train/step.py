@@ -20,17 +20,15 @@ with per-layer remat this bounds activation memory to one microbatch.
 from __future__ import annotations
 
 import dataclasses
-import math
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.configs.base import Arch, TuningConfig
+from repro.configs.base import Arch
 from repro.core import fused_cross_entropy, LossConfig
 from repro.core.fused_ce import default_impl
-from repro.core.windows import BlockPlan
 from repro.core.sharded import make_sharded_loss
 from repro.models.registry import cast_params, forward_hidden
 from repro.optim import make_optimizer, clip_by_global_norm
@@ -56,7 +54,6 @@ class TrainConfig:
     grad_accum: int = 1
     accum_dtype: str = "float32"   # grad-accumulation buffer dtype
     zero3: bool = False
-    tuning: TuningConfig = TuningConfig()   # block-plan autotuning
 
     def make_schedule(self):
         if self.schedule == "warmup_cosine":
@@ -74,32 +71,6 @@ def _loss_cfg(arch: Arch, tc: TrainConfig) -> LossConfig:
     return arch.loss_config(
         block_v=tc.loss_block_v, label_smoothing=tc.label_smoothing,
         z_loss=tc.z_loss, grad_filter_eps=tc.grad_filter_eps)
-
-
-def resolve_block_plan(tc: TrainConfig, lcfg: LossConfig, n_rows: int,
-                       vocab: int, d: int, dtype) -> Optional[BlockPlan]:
-    """Tune-once plan resolution for the train step (None when disabled).
-
-    The first resolution for a given (shape, dtype, backend) key runs the
-    autotuner trials; every later call — including re-traces and later
-    processes sharing the cache file — is a pure cache hit, so the tuned
-    plan is effectively chosen once at startup and reused per step.
-    """
-    if not tc.tuning.enabled:
-        return None
-    from repro.kernels.fused_ce.autotune import autotune_plan
-    from repro.tuning import get_cache
-    t = tc.tuning
-    return autotune_plan(
-        n_rows, vocab, d, dtype, cfg=lcfg, cache=get_cache(t.cache_path),
-        trial_budget=t.trial_budget, trial_iters=t.trial_iters)
-
-
-def _shard_counts(mesh, rows_axes: Tuple[str, ...],
-                  vocab_axis: str = "model") -> Tuple[int, int]:
-    """(row shards, vocab shards) of the sharded-loss layout."""
-    rows = math.prod(mesh.shape[a] for a in rows_axes) if rows_axes else 1
-    return rows, mesh.shape[vocab_axis]
 
 
 def _streaming_accuracy(rows, w, targets, lcfg: LossConfig) -> jax.Array:
@@ -125,10 +96,10 @@ def build_loss_fn(arch: Arch, tc: TrainConfig,
     horizon 0 is the trunk CE on batch['targets']; head h adds its weight
     times the fused CE of the head-h hiddens against the targets shifted
     left by h (IGNORE_INDEX tails).  All horizons share ONE BlockPlan —
-    identical (rows, vocab, d, dtype) keys, so the autotuner tunes once —
-    and report per-horizon ce_h*/acc_h* metrics.  Zero-weight horizons are
-    statically dropped from the total (their gradients are exactly zero)
-    but still measured.
+    the same (rows, vocab, d, dtype), so `choose_blocks` gives them the
+    same tiles — and report per-horizon ce_h*/acc_h* metrics.  Zero-weight
+    horizons are statically dropped from the total (their gradients are
+    exactly zero) but still measured.
     """
     lcfg = _loss_cfg(arch, tc)
     mesh = rules.mesh if rules is not None else None
@@ -143,23 +114,9 @@ def build_loss_fn(arch: Arch, tc: TrainConfig,
     rows_axes = tuple(a for a in ("pod", "data")
                       if a in mesh.axis_names) if use_sharded else ()
     layout = "sp_gather" if tc.loss_impl == "sharded_sp" else "2d"
-
-    # built lazily at trace time (shapes are concrete there, which is what
-    # lets the autotuner key on the per-shard local panel); memoized so the
-    # shard_map closures and the tuned plan are constructed exactly once
-    sharded_cache: Dict[Tuple[int, int], Callable] = {}
-
-    def sharded_loss(n_rows, vocab, d, dtype):
-        key = (n_rows, vocab)
-        if key not in sharded_cache:
-            n_row_shards, n_vocab_shards = _shard_counts(mesh, rows_axes)
-            plan = resolve_block_plan(
-                tc, lcfg, n_rows // n_row_shards, vocab // n_vocab_shards,
-                d, dtype)
-            sharded_cache[key] = make_sharded_loss(
-                mesh, lcfg, rows_axes=rows_axes, vocab_axis="model",
-                layout=layout, impl=default_impl(), plan=plan)
-        return sharded_cache[key]
+    sharded_ce = make_sharded_loss(
+        mesh, lcfg, rows_axes=rows_axes, vocab_axis="model", layout=layout,
+        impl=default_impl()) if use_sharded else None
 
     def loss_fn(params, batch):
         # one cast of the master weights, the lm_head's included, for the
@@ -183,21 +140,12 @@ def build_loss_fn(arch: Arch, tc: TrainConfig,
         w = params["lm_head"]
 
         if use_sharded:
-            sfn = sharded_loss(rows.shape[0], w.shape[0], d, rows.dtype)
-
             def ce_of(r, y):
-                return sfn(r, w, y)
+                return sharded_ce(r, w, y)
         else:
-            impl = tc.loss_impl
-            plan = None
-            if impl in ("streaming", "pallas", "auto"):
-                # resolved ONCE; every horizon streams the same panel shape
-                plan = resolve_block_plan(tc, lcfg, rows.shape[0],
-                                          w.shape[0], d, rows.dtype)
-
             def ce_of(r, y):
-                return fused_cross_entropy(r, w, y, impl=impl, cfg=lcfg,
-                                           plan=plan)
+                return fused_cross_entropy(r, w, y, impl=tc.loss_impl,
+                                           cfg=lcfg)
 
         targets0 = batch["targets"].reshape(-1)
         ce0 = ce_of(rows, targets0)
@@ -224,34 +172,6 @@ def build_loss_fn(arch: Arch, tc: TrainConfig,
         return loss, dict(metrics, ce=ce, aux=aux)
 
     return loss_fn
-
-
-def make_tuning_prewarm(arch: Arch, tc: TrainConfig, n_rows: int,
-                        rules: Optional[AxisRules] = None) -> Callable:
-    """`on_start` hook for `train_loop`: populate the tuning cache for the
-    training shape BEFORE step 0, so trial timing never pollutes the
-    compiled step or the per-step timings.  `n_rows` is the GLOBAL batch
-    rows (global_batch * seq_len); microbatching is applied here.
-    Best-effort — if the traced row count differs (e.g. frontend tokens),
-    the trace-time resolution in `build_loss_fn` re-tunes for the exact
-    shape.
-    """
-    def hook():
-        if not tc.tuning.enabled:
-            return
-        lcfg = _loss_cfg(arch, tc)
-        dtype = jnp.dtype(getattr(arch.cfg, "compute_dtype", "float32"))
-        vocab = arch.padded_vocab
-        # the loss sees one microbatch at a time under grad accumulation
-        n = n_rows // max(tc.grad_accum, 1)
-        mesh = rules.mesh if rules is not None else None
-        if tc.loss_impl in ("sharded", "sharded_sp") and mesh is not None:
-            rows_axes = tuple(a for a in ("pod", "data")
-                              if a in mesh.axis_names)
-            n_row_shards, n_vocab_shards = _shard_counts(mesh, rows_axes)
-            n, vocab = n // n_row_shards, vocab // n_vocab_shards
-        resolve_block_plan(tc, lcfg, n, vocab, arch.cfg.d_model, dtype)
-    return hook
 
 
 def build_train_step(arch: Arch, tc: TrainConfig,

@@ -91,13 +91,12 @@ def mesh_1x1():
     return Mesh(dev, ("data", "model"))
 
 
-def sharded_grads(h, w, y, cfg, layout="2d", impl="streaming", mesh=None,
-                  plan=None):
+def sharded_grads(h, w, y, cfg, layout="2d", impl="streaming", mesh=None):
     """(dh, dw) through the shard_map custom_vjp builder (1x1 mesh by
     default: identical collective schedule, single shard)."""
     loss_fn = make_sharded_loss(mesh or mesh_1x1(), cfg,
                                 rows_axes=("data",), vocab_axis="model",
-                                layout=layout, impl=impl, plan=plan)
+                                layout=layout, impl=impl)
     return jax.grad(loss_fn, (0, 1))(h, w, y)
 
 

@@ -1,23 +1,35 @@
 """Pallas fused-CE kernels (interpret mode) vs the pure-jnp ref.py oracle
-and the canonical autodiff loss.
+and the canonical autodiff loss, and the tile plan every vocabulary
+kernel runs.
 
 Required per-kernel validation: sweep shapes/dtypes and assert_allclose
 the forward stats and the backward kernel's dH and dW, in both of the
 backward's orientations (dW or dH read-modify-written), against ref.py
 and `tests/grad_oracle.py`.
+
+The plan is a function of the shape (DESIGN.md §3.2): the entry points
+are traced over abstract inputs, nothing allocated or run, and the
+`pallas_call` they stage must carry `choose_blocks`' tiles.
 """
+
+import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import LossConfig, canonical_loss
+from repro.analysis.lint.jaxpr import walk
+from repro.core import LossConfig, canonical_loss, streaming_loss
 from repro.core.streaming import _row_scale
-from repro.core.windows import BlockPlan, choose_blocks, tile_bytes
+from repro.core.windows import (BlockPlan, VMEM_BYTES, bwd_tile_bytes,
+                                choose_blocks, tile_bytes, _DEFAULT_BUDGET)
 from repro.kernels.fused_ce import kernel as K
 from repro.kernels.fused_ce.ops import pallas_loss
 from repro.kernels.fused_ce.ref import ref_stats, ref_grads
+from repro.kernels.sample_topk import pallas_topk
+from repro.kernels.score_tokens import pallas_score_tokens
+from repro.models.attention import AttnConfig, attention_layer, init_attention
 
 from grad_oracle import assert_grads_close, make_problem, oracle_grads
 
@@ -208,3 +220,215 @@ def test_bwd_kernel_bitwise_repeatable(plan):
     for x, z in zip(a, b):
         np.testing.assert_array_equal(np.asarray(x).view(np.uint32),
                                       np.asarray(z).view(np.uint32))
+
+
+# ---------------------------------------------------------------------------
+# choose_blocks edge cases
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("n", [1, 3, 7])
+def test_choose_blocks_tiny_decode_rows(n):
+    """decode shapes (B*T == B): rows tile floors at one sublane group."""
+    plan = choose_blocks(n, 262144, 4096, in_bytes=2)
+    assert plan.block_rows == 8
+    assert plan.block_v % 128 == 0
+    assert tile_bytes(plan.block_rows, plan.block_v, 4096) <= \
+        _DEFAULT_BUDGET
+
+
+def test_choose_blocks_vmem_shrink_loop():
+    """an unsatisfiable budget bottoms out at the aligned floor tiles
+    instead of looping forever or misaligning."""
+    plan = choose_blocks(4096, 262144, 4096, in_bytes=2,
+                         vmem_budget=200_000)
+    assert (plan.block_rows, plan.block_v) == (8, 128)
+
+
+def test_choose_blocks_fits_generous_budget():
+    plan = choose_blocks(4096, 262144, 1024, in_bytes=2)
+    assert tile_bytes(plan.block_rows, plan.block_v, 1024) <= \
+        _DEFAULT_BUDGET
+    assert plan.block_rows % 8 == 0 and plan.block_v % 128 == 0
+
+
+# ---------------------------------------------------------------------------
+# an explicit plan, threaded end to end
+# ---------------------------------------------------------------------------
+
+
+def _small_problem(seed=0):
+    """16 rows of d=32 against a 256-row vocabulary, in f32."""
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+    h = jax.random.normal(k1, (16, 32)) * 0.5
+    w = jax.random.normal(k2, (256, 32)) * 0.05
+    y = jax.random.randint(k3, (16,), 0, 256)
+    return h, w, y
+
+
+def test_pallas_and_streaming_accept_explicit_plan():
+    h, w, y = _small_problem()
+    cfg = LossConfig(block_v=64)
+    plan = BlockPlan(16, 128, 0)        # not choose_blocks' (16, 256)
+    base = streaming_loss(h, w, y, cfg)
+    via_stream = streaming_loss(h, w, y, cfg, plan=plan)
+    via_pallas = pallas_loss(h, w, y, cfg, plan=plan)
+    np.testing.assert_allclose(float(base), float(via_stream), rtol=1e-5)
+    np.testing.assert_allclose(float(base), float(via_pallas), rtol=1e-5)
+
+
+def test_pallas_loss_grads_with_explicit_plan():
+    h, w, y = _small_problem()
+    cfg = LossConfig(block_v=64)
+    plan = BlockPlan(8, 128, 0)
+    ref = jax.grad(lambda h, w: streaming_loss(h, w, y, cfg), (0, 1))(h, w)
+    got = jax.grad(lambda h, w: pallas_loss(h, w, y, cfg, plan=plan),
+                   (0, 1))(h, w)
+    np.testing.assert_allclose(np.asarray(ref[0]), np.asarray(got[0]),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(ref[1]), np.asarray(got[1]),
+                               rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the plan each entry point traces: choose_blocks of the shape
+# ---------------------------------------------------------------------------
+
+_S = jax.ShapeDtypeStruct
+
+# entry -> (function of (h, w, y), the kernel it stages, and the places of
+# the (rows, d) H block and the (vocab, d) W block among its inputs)
+PLAN_ENTRIES = {
+    "loss_fwd": (lambda h, w, y: pallas_loss(h, w, y), "fused_ce_fwd", 2, 3),
+    "loss_bwd": (jax.grad(lambda h, w, y: pallas_loss(h, w, y), (0, 1)),
+                 "fused_ce_bwd", 5, 6),
+    "topk": (lambda h, w, y: pallas_topk(h, w, 4), "sample_topk", 1, 2),
+    "score": (lambda h, w, y: pallas_score_tokens(h, w, y[:, None]),
+              "score_tokens", 2, 3),
+}
+# (rows, vocab, d, dtype): the training cells, two decode batches at the
+# cells' vocabulary, and a vocabulary smaller than choose_blocks' widest
+# tile
+PLAN_SHAPES = {
+    "cell_f32": (16384, 151936, 1024, jnp.float32),
+    "cell_bf16": (16384, 151936, 1024, jnp.bfloat16),
+    "decode_1": (1, 151936, 1024, jnp.bfloat16),
+    "decode_8": (8, 151936, 1024, jnp.bfloat16),
+    "vocab_512": (40, 512, 128, jnp.float32),
+}
+
+
+def _pallas_calls(jaxpr):
+    """The `pallas_call` equations of a jaxpr and its sub-jaxprs."""
+    return [e for _, e in walk(jaxpr) if e.primitive.name == "pallas_call"]
+
+
+def _traced_kernel(entry, n, v, d, dtype):
+    """(equation, grid mapping, bm, bv) of the kernel `entry` stages for
+    an (n, d) x (v, d) problem, traced over abstract inputs."""
+    fn, name, h_at, w_at = PLAN_ENTRIES[entry]
+    jaxpr = jax.make_jaxpr(fn)(_S((n, d), dtype), _S((v, d), dtype),
+                               _S((n,), jnp.int32))
+    eqn, = [e for e in _pallas_calls(jaxpr) if e.params["name"] == name]
+    gm = eqn.params["grid_mapping"]
+    blocks = [tuple(getattr(b, "block_size", b) for b in m.block_shape)
+              for m in gm.block_mappings]
+    (bm, h_d), (bv, w_d) = blocks[h_at], blocks[w_at]
+    assert h_d == w_d == d
+    return eqn, gm, bm, bv
+
+
+@pytest.mark.parametrize("shape", PLAN_SHAPES)
+@pytest.mark.parametrize("entry", PLAN_ENTRIES)
+def test_entry_point_runs_the_shape_plan(entry, shape):
+    """The staged kernel tiles by `choose_blocks` at the hidden states'
+    itemsize, on an (8, 128)-aligned grid, and its VMEM model fits the
+    scoped limit the kernel asks for (the compiler's default when it
+    asks for none)."""
+    n, v, d, dtype = PLAN_SHAPES[shape]
+    in_bytes = jnp.dtype(dtype).itemsize
+    eqn, gm, bm, bv = _traced_kernel(entry, n, v, d, dtype)
+    assert (bm, bv) == choose_blocks(n, v, d, in_bytes=in_bytes).shape
+    assert bm % 8 == 0 and bv % 128 == 0
+    grid = (-(-n // bm), -(-v // bv))
+    if entry == "loss_bwd":
+        rmw_dw = gm.grid == grid
+        assert rmw_dw or gm.grid == grid[::-1]
+        assert gm.scratch_avals[0].shape == (3, bv if rmw_dw else bm, d)
+        model = bwd_tile_bytes(bm, bv, d, in_bytes, rmw_dw=rmw_dw)
+    else:
+        assert gm.grid == grid
+        model = tile_bytes(bm, bv, d, in_bytes)
+    limit = eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+    assert model <= (limit or VMEM_BYTES)
+
+
+@pytest.mark.parametrize("dtype,plan,rmw", [
+    (jnp.float32, (512, 256), "dW"),
+    (jnp.bfloat16, (512, 1024), "dH"),
+], ids=("f32", "bf16"))
+def test_cell_plan_pinned(dtype, plan, rmw):
+    """The training cells' loss runs (512, 256) tiles in f32, dW read,
+    modified and written, and (512, 1024) in bf16, dH read, modified and
+    written — forward and backward alike, over Qwen3-0.6B's vocabulary
+    and the 152064 rows the program pads its lm_head to."""
+    n, d = 16384, 1024
+    for v in (151936, 152064):
+        assert choose_blocks(
+            n, v, d, in_bytes=jnp.dtype(dtype).itemsize).shape == plan
+        for entry in ("loss_fwd", "loss_bwd"):
+            _, gm, bm, bv = _traced_kernel(entry, n, v, d, dtype)
+            assert (bm, bv) == plan
+        # the backward's three-slot VMEM buffer holds the read-modify-
+        # written gradient's blocks: dW's (bv, d) or dH's (bm, d)
+        num_r, num_v = -(-n // bm), -(-v // bv)
+        if rmw == "dW":
+            assert gm.scratch_avals[0].shape == (3, bv, d)
+            assert gm.grid == (num_r, num_v)
+        else:
+            assert gm.scratch_avals[0].shape == (3, bm, d)
+            assert gm.grid == (num_v, num_r)
+
+
+def _paged_decode_grid(b, nkv, hd, bs, nb):
+    """Grid of the paged-attention kernel a one-token paged decode step
+    stages, traced over abstract params and cache."""
+    cfg = AttnConfig(d_model=2 * nkv * hd, num_heads=2 * nkv,
+                     num_kv_heads=nkv, head_dim=hd, paged_impl="pallas")
+    params = jax.eval_shape(
+        lambda: init_attention(jax.random.PRNGKey(0), cfg))
+    pool = _S((b * nb + 1, bs, nkv, hd), jnp.float32)
+    cache = {"kp": pool, "vp": pool, "table": _S((b, nb), jnp.int32),
+             "len": _S((b,), jnp.int32)}
+    jaxpr = jax.make_jaxpr(
+        lambda p, x, c: attention_layer(p, x, cfg, cache=c))(
+            params, _S((b, 1, cfg.d_model), jnp.float32), cache)
+    eqn, = _pallas_calls(jaxpr)
+    return eqn.params["grid_mapping"].grid
+
+
+@pytest.mark.parametrize("entry", [*PLAN_ENTRIES, "paged"])
+def test_plan_ignores_outside_state(entry, tmp_path, monkeypatch):
+    """A block-plan file in the format an earlier empirical tuner wrote,
+    at both places it was read from (`$REPRO_TUNING_CACHE` and
+    `~/.cache/repro/blockplans.json`), names other tiles for the shape
+    and four pages a step: the traced kernels still run `choose_blocks`'
+    plan, and paged decode one page a step."""
+    n, v, d = 64, 512, 128
+    b, nkv, hd, bs, nb = 2, 2, 16, 8, 4
+    backend = jax.default_backend()
+    stale = {"block_rows": 8, "block_v": 128, "vmem_bytes": 0, "us": 1.0}
+    entries = {f"{n}x{v}x{d}:float32:{backend}{op}": stale
+               for op in ("", ":topk4", ":score1")}
+    entries[f"{b}x{nb * bs}x{nkv * hd}:float32:{backend}:pattn{bs}"] = \
+        dict(stale, block_v=nb * bs)
+    path = tmp_path / ".cache" / "repro" / "blockplans.json"
+    path.parent.mkdir(parents=True)
+    path.write_text(json.dumps({"version": 1, "entries": entries}))
+    monkeypatch.setenv("HOME", str(tmp_path))
+    monkeypatch.setenv("REPRO_TUNING_CACHE", str(path))
+    if entry == "paged":
+        assert _paged_decode_grid(b, nkv, hd, bs, nb) == (b, nb)
+        return
+    _, _, bm, bv = _traced_kernel(entry, n, v, d, jnp.float32)
+    assert (bm, bv) == choose_blocks(n, v, d, in_bytes=4).shape == (64, 512)

@@ -9,11 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.paged_attn import (autotune_paged_plan,
-                                      lookup_paged_plan,
-                                      pallas_paged_attention,
-                                      plan_pages_per_step)
-from repro.core.windows import BlockPlan
+from repro.kernels.paged_attn import pallas_paged_attention
 from repro.models.attention import (AttnConfig, decode_attention,
                                     gather_paged_kv, _paged_update)
 
@@ -119,22 +115,6 @@ def test_paged_update_matches_slab_update_content():
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
-def test_autotune_and_lookup(tmp_path, monkeypatch):
-    """The pages-per-step plan rides the shared tuning-cache machinery
-    (per-path singletons: pointing the env var at a tmp file isolates)."""
-    monkeypatch.setenv("REPRO_TUNING_CACHE", str(tmp_path / "plans.json"))
-    assert lookup_paged_plan(2, 1, 2, 16, 4, 8, jnp.float32) == 1  # miss
-    ppb = autotune_paged_plan(2, 1, 4, 2, 16, 4, 8, jnp.float32,
-                              trial_budget=2, trial_iters=1)
-    assert ppb >= 1
-    assert lookup_paged_plan(2, 1, 2, 16, 4, 8, jnp.float32) == ppb
-
-
-def test_plan_pages_per_step_bounds():
-    assert plan_pages_per_step(BlockPlan(8, 128, 0), 16, 4) == 4   # capped
-    assert plan_pages_per_step(BlockPlan(8, 128, 0), 256, 8) == 1  # floor
-
-
 # ---------------------------------------------------------------------------
 # quantized pools: in-register dequant vs the slab _decode_quantized oracle
 # ---------------------------------------------------------------------------
@@ -198,18 +178,3 @@ def test_quantized_kernel_requires_both_scales():
         rng, 1, 1, 2, 1, 8, 4, 2)
     with pytest.raises(ValueError, match="vp_scale"):
         pallas_paged_attention(q, kp, vp, table, lens, kp_scale=kps)
-
-
-def test_quantized_autotune_keys_do_not_shadow_bf16(tmp_path, monkeypatch):
-    """int8 and bf16 winners are memoized under distinct keys; a lookup
-    for one precision never returns the other's plan."""
-    monkeypatch.setenv("REPRO_TUNING_CACHE", str(tmp_path / "plans.json"))
-    kw = dict(trial_budget=2, trial_iters=1)
-    autotune_paged_plan(2, 1, 4, 2, 16, 4, 8, jnp.float32,
-                        wdtype="int8", **kw)
-    assert lookup_paged_plan(2, 1, 2, 16, 4, 8, jnp.float32) == 1  # miss
-    ppb_q = lookup_paged_plan(2, 1, 2, 16, 4, 8, jnp.float32,
-                              wdtype="int8")
-    assert ppb_q >= 1
-    ppb_f = autotune_paged_plan(2, 1, 4, 2, 16, 4, 8, jnp.float32, **kw)
-    assert lookup_paged_plan(2, 1, 2, 16, 4, 8, jnp.float32) == ppb_f

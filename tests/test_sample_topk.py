@@ -15,11 +15,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.windows import BlockPlan, choose_blocks, tile_bytes
-from repro.kernels.sample_topk import (pallas_topk, run_topk_trials,
-                                       autotune_topk_plan, lookup_topk_plan)
+from repro.core.windows import BlockPlan, tile_bytes
+from repro.kernels.sample_topk import pallas_topk
 from repro.serve.sampler import streaming_topk
-from repro.tuning import TuningCache, plan_key
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -125,34 +123,6 @@ def test_topk_col_offset_shards_merge():
     np.testing.assert_allclose(np.asarray(gv), np.asarray(full_v),
                                rtol=1e-5)
     np.testing.assert_array_equal(np.asarray(gi), np.asarray(full_i))
-
-
-def test_plan_key_topk_namespaced():
-    """The top-k cache entries never shadow fused-CE entries (and k is
-    part of the namespace: greedy and top-40 tune independently)."""
-    ce = plan_key(8, 512, 64, "float32", "cpu")
-    t1 = plan_key(8, 512, 64, "float32", "cpu", op="topk1")
-    t40 = plan_key(8, 512, 64, "float32", "cpu", op="topk40")
-    assert len({ce, t1, t40}) == 3
-    assert ce == "8x512x64:float32:cpu"      # legacy CE keys unchanged
-
-
-def test_topk_autotune_cache_roundtrip(tmp_path):
-    cache = TuningCache(str(tmp_path / "plans.json"))
-    plan = autotune_topk_plan(8, 256, 32, 4, jnp.float32, cache=cache,
-                              trial_budget=2, trial_iters=1)
-    hit = lookup_topk_plan(8, 256, 32, 4, jnp.float32, cache=cache)
-    assert hit.shape == plan.shape
-    # a different k is a different key -> heuristic fallback
-    miss = lookup_topk_plan(8, 256, 32, 9, jnp.float32, cache=cache)
-    assert miss.shape == choose_blocks(8, 256, 32, in_bytes=4).shape
-
-
-def test_topk_trials_best_not_worse_than_heuristic():
-    res = run_topk_trials(8, 256, 32, 4, jnp.float32, trial_budget=3,
-                          trial_iters=1)
-    assert res.best_us <= res.heuristic_us
-    assert any(p.shape == res.heuristic.shape for p, _ in res.trials)
 
 
 if _HAVE_HYPOTHESIS:

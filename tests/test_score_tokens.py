@@ -13,13 +13,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.windows import BlockPlan, choose_blocks, tile_bytes
+from repro.core.windows import BlockPlan, tile_bytes
 from repro.kernels.score_tokens import (pallas_score_tokens, score_stats,
-                                        streaming_score,
-                                        autotune_score_plan,
-                                        lookup_score_plan,
-                                        run_score_trials)
-from repro.tuning import TuningCache, plan_key
+                                        streaming_score)
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -171,34 +167,6 @@ def test_score_col_offset_shards_merge():
                                rtol=1e-5)
     np.testing.assert_allclose(np.asarray(zt - lse[:, None]),
                                np.asarray(full_lp), rtol=1e-5, atol=1e-5)
-
-
-def test_plan_key_score_namespaced():
-    """Score cache entries never shadow fused-CE or top-k entries (P is
-    part of the namespace: 1-candidate and 8-candidate tune apart)."""
-    ce = plan_key(8, 512, 64, "float32", "cpu")
-    s1 = plan_key(8, 512, 64, "float32", "cpu", op="score1")
-    s8 = plan_key(8, 512, 64, "float32", "cpu", op="score8")
-    t1 = plan_key(8, 512, 64, "float32", "cpu", op="topk1")
-    assert len({ce, s1, s8, t1}) == 4
-
-
-def test_score_autotune_cache_roundtrip(tmp_path):
-    cache = TuningCache(str(tmp_path / "plans.json"))
-    plan = autotune_score_plan(8, 256, 32, 1, jnp.float32, cache=cache,
-                               trial_budget=2, trial_iters=1)
-    hit = lookup_score_plan(8, 256, 32, 1, jnp.float32, cache=cache)
-    assert hit.shape == plan.shape
-    # a different candidate count is a different key -> heuristic
-    miss = lookup_score_plan(8, 256, 32, 9, jnp.float32, cache=cache)
-    assert miss.shape == choose_blocks(8, 256, 32, in_bytes=4).shape
-
-
-def test_score_trials_best_not_worse_than_heuristic():
-    res = run_score_trials(8, 256, 32, 1, jnp.float32, trial_budget=3,
-                           trial_iters=1)
-    assert res.best_us <= res.heuristic_us
-    assert any(p.shape == res.heuristic.shape for p, _ in res.trials)
 
 
 if _HAVE_HYPOTHESIS:
